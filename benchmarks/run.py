@@ -35,6 +35,7 @@ from benchmarks.roofline import csv_rows, load_rows
 from repro.baselines import make_method
 from repro.baselines.sizey_method import SizeyMethod
 from repro.core import SizeyConfig
+from repro.utils import enable_compilation_cache
 from repro.workflow import WORKFLOWS, generate_workflow, simulate
 
 METHODS = ("sizey", "witt_wastage", "witt_lr", "tovar_ppm",
@@ -243,6 +244,7 @@ def main() -> None:
                          "so the committed baseline stays intact for the "
                          "check_regression gate)")
     args = ap.parse_args()
+    enable_compilation_cache()
     if args.smoke:
         args.scale = 0.05
         args.ttf = [1.0]

@@ -5,6 +5,7 @@
 from repro.baselines import make_method
 from repro.baselines.sizey_method import SizeyMethod
 from repro.core import SizeyConfig
+from repro.utils import enable_compilation_cache
 from repro.workflow import generate_workflow, simulate
 
 
@@ -15,6 +16,7 @@ def main():
     ap.add_argument("--scale", type=float, default=0.2,
                     help="trace scale factor (default 0.2)")
     args = ap.parse_args()
+    enable_compilation_cache()
     # mag has the most instances per task type (Table I: 720) — the
     # regime where online learning has room even at reduced scale
     trace = generate_workflow("mag", scale=args.scale)

@@ -6,8 +6,10 @@ backbone) with Sizey-sized KV caches.
 import sys
 
 from repro.launch.serve import main as serve_main
+from repro.utils import enable_compilation_cache
 
 if __name__ == "__main__":
+    enable_compilation_cache()
     # forward CLI args to the serving launcher (so --help and overrides
     # work); with none, run the documented musicgen demo configuration
     argv = sys.argv[1:] or ["--arch", "musicgen-large", "--requests", "16",

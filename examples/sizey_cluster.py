@@ -19,6 +19,7 @@ import numpy as np
 from repro.configs import SHAPES, get_config
 from repro.core import SizeyConfig
 from repro.launch.sizing import SizeyJobSizer
+from repro.utils import enable_compilation_cache
 
 DRYRUN = os.environ.get("REPRO_DRYRUN_RESULTS", "results/dryrun.jsonl")
 
@@ -42,6 +43,7 @@ def main():
                     help="dry-run results JSONL (default: "
                          "$REPRO_DRYRUN_RESULTS or results/dryrun.jsonl)")
     args = ap.parse_args()
+    enable_compilation_cache()
     DRYRUN = args.dryrun
     cells = load_cells()
     if not cells:

@@ -7,8 +7,10 @@ with checkpoint/restart and Sizey-sized memory (assignment deliverable b).
 import sys
 
 from repro.launch.train import main as train_main
+from repro.utils import enable_compilation_cache
 
 if __name__ == "__main__":
+    enable_compilation_cache()
     if "--quick" in sys.argv:
         argv = ["--arch", "granite-3-2b", "--scale", "e2e-100m",
                 "--steps", "40", "--batch", "4", "--seq", "128",

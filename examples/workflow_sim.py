@@ -88,6 +88,7 @@ from repro.data import load_trace, read_nodes_info
 from repro.baselines.sizey_method import SizeyMethod
 from repro.core import SizeyConfig
 from repro.obs.quality import QUALITY_FIELDS, read_quality_rows
+from repro.utils import enable_compilation_cache
 from repro.workflow import (FAILURE_STRATEGIES, WORKFLOWS, generate_workflow,
                             node_specs_from_caps, node_specs_from_racks,
                             simulate, simulate_cluster)
@@ -336,6 +337,7 @@ def main():
                          "with examples/quality_report.py")
     ap.add_argument("--out", default="results/workflow_sim.csv")
     args = ap.parse_args()
+    enable_compilation_cache()
     if args.failure_strategy == "auto" and not (args.risk and args.cluster):
         ap.error("--failure-strategy auto selects per pool from the risk "
                  "signals; combine it with --risk and --cluster")

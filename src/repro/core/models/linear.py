@@ -9,9 +9,13 @@ from __future__ import annotations
 
 from typing import NamedTuple
 
+import jax
 import jax.numpy as jnp
 
 from repro.core.config import SizeyConfig
+
+# f32 dots at full precision: the TPU's default is one bf16 pass
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
 class LinearState(NamedTuple):
@@ -50,8 +54,8 @@ def init(d: int, cfg: SizeyConfig) -> LinearState:
 def fit(xs: jnp.ndarray, ys: jnp.ndarray, mask: jnp.ndarray, key,
         cfg: SizeyConfig) -> LinearState:
     xa = _aug(xs) * mask[:, None]
-    xtx = xa.T @ xa
-    xty = xa.T @ (ys * mask)
+    xtx = jnp.dot(xa.T, xa, precision=_HIGHEST)
+    xty = jnp.dot(xa.T, ys * mask, precision=_HIGHEST)
     return LinearState(xtx, xty, _solve(xtx, xty, cfg.ridge_lambda))
 
 
@@ -66,9 +70,9 @@ def update(state: LinearState, xs: jnp.ndarray, ys: jnp.ndarray,
 
 
 def predict(state: LinearState, x: jnp.ndarray) -> jnp.ndarray:
-    return _aug(x[None, :])[0] @ state.w
+    return jnp.dot(_aug(x[None, :])[0], state.w, precision=_HIGHEST)
 
 
 def predict_batch(state: LinearState, xs: jnp.ndarray) -> jnp.ndarray:
     """Vectorized predict over a (K, d) feature block -> (K,)."""
-    return _aug(xs) @ state.w
+    return jnp.dot(_aug(xs), state.w, precision=_HIGHEST)

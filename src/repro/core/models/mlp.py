@@ -20,6 +20,8 @@ from repro.core.config import SizeyConfig
 
 _EPS = 1e-6
 HPO_LRS = (0.03, 0.01, 0.003)
+# f32 dots at full precision: the TPU's default is one bf16 pass
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
 class MLPState(NamedTuple):
@@ -48,8 +50,8 @@ def _params(state: MLPState):
 
 def _forward(params, x):
     w1, b1, w2, b2 = params
-    h = jnp.tanh(x @ w1 + b1)
-    return (h @ w2 + b2)[..., 0]
+    h = jnp.tanh(jnp.dot(x, w1, precision=_HIGHEST) + b1)
+    return (jnp.dot(h, w2, precision=_HIGHEST) + b2)[..., 0]
 
 
 def _norm_stats(xs, ys, mask):

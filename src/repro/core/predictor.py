@@ -85,8 +85,13 @@ FIT_KIND = "fit"
 
 
 def pallas_available() -> bool:
-    """Compiled Pallas kernels only make sense on an accelerator backend;
-    on CPU Pallas runs in interpret mode, far slower than plain jnp."""
+    """Whether the default backend compiles Pallas kernels (TPU, GPU).
+
+    This picks ``SizeyPredictor.use_pallas`` when the caller does not: the
+    MLP forward then runs the ``ensemble_mlp`` kernel. On CPU Pallas would
+    run in interpret mode, far slower than plain jnp, so the CPU takes the
+    jnp forward. The route taken is counted at trace time in
+    ``TRACE_COUNTS["mlp_pallas"]`` / ``TRACE_COUNTS["mlp_jnp"]``."""
     return jax.default_backend() in ("tpu", "gpu")
 
 
@@ -220,7 +225,8 @@ def _decision_cache_core(strategy: str, alpha: float, beta: float,
                                      ttf)
     acc_w = gate_weights(raq_scores(acc, jnp.zeros_like(acc), 0.0),
                          strategy, beta)
-    ins_agg = acc_w @ insample_preds
+    ins_agg = jnp.dot(acc_w, insample_preds,
+                      precision=jax.lax.Precision.HIGHEST)
     off_ins, idx_ins = select_offset(ys - ins_agg, ins_agg, ys, runtimes,
                                      mask, ttf)
     young = jnp.sum(log_mask) < 5
@@ -287,6 +293,7 @@ def _pool_model_preds(models: tuple[str, ...], cfg: SizeyConfig,
         if m == "knn":
             cols.append(mod.predict_batch(states[i], xb, k=cfg.knn_k))
         elif m == "mlp":
+            TRACE_COUNTS["mlp_pallas" if use_pallas else "mlp_jnp"] += 1
             cols.append(mod.predict_batch(states[i], xb,
                                           use_pallas=use_pallas))
         else:

@@ -17,18 +17,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-# compat: jax.shard_map / jax.lax.pvary graduated from experimental after
-# 0.4.x; on older jax fall back to the experimental entry point and treat
-# pvary as identity (no varying-axis type system there).
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-    _pvary = jax.lax.pvary
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def _pvary(x, axes):
-        return x
-
 
 def pipeline_apply(stage_fn, stage_params, x_microbatches, *,
                    mesh: Mesh, axis: str = "stage"):
@@ -67,17 +55,19 @@ def pipeline_apply(stage_fn, stage_params, x_microbatches, *,
             return (buf, out), None
 
         # initial carries are device-varying (each stage evolves its own)
-        buf0 = _pvary(jnp.zeros((mb, d), x_all.dtype), (axis,))
-        out0 = _pvary(jnp.zeros((m, mb, d), x_all.dtype), (axis,))
+        buf0 = jax.lax.pvary(jnp.zeros((mb, d), x_all.dtype), (axis,))
+        out0 = jax.lax.pvary(jnp.zeros((m, mb, d), x_all.dtype), (axis,))
         (_, out), _ = jax.lax.scan(tick, (buf0, out0), jnp.arange(ticks))
         # only the last stage holds real outputs; broadcast via psum
         out = jnp.where(stage == n_stages - 1, out, jnp.zeros_like(out))
         return jax.lax.psum(out, axis)
 
     spec_params = jax.tree.map(lambda _: P(axis), stage_params)
-    fn = _shard_map(shmapped, mesh=mesh,
-                    in_specs=(spec_params, P()), out_specs=P())
-    return fn(stage_params, x_microbatches)
+    fn = jax.shard_map(shmapped, in_specs=(spec_params, P()), out_specs=P())
+    # shard_map takes its mesh from the context: jax.set_mesh also places
+    # the scan's loop counters on it when the mesh's axes are Explicit
+    with jax.set_mesh(mesh):
+        return fn(stage_params, x_microbatches)
 
 
 def split_stages(layer_params, n_stages: int):

@@ -11,6 +11,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+# f32 dots at full precision: the TPU's default is one bf16 pass
+_HIGHEST = jax.lax.Precision.HIGHEST
+
 
 def _mlp_body(x_ref, w1_ref, b1_ref, w2_ref, b2_ref, o_ref):
     x = x_ref[0].astype(jnp.float32)          # (bt, d)
@@ -19,9 +22,10 @@ def _mlp_body(x_ref, w1_ref, b1_ref, w2_ref, b2_ref, o_ref):
     w2 = w2_ref[0].astype(jnp.float32)        # (h, 1)
     b2 = b2_ref[0].astype(jnp.float32)        # (1,)
     hid = jnp.tanh(jax.lax.dot_general(
-        x, w1, (((1,), (0,)), ((), ())),
+        x, w1, (((1,), (0,)), ((), ())), precision=_HIGHEST,
         preferred_element_type=jnp.float32) + b1[None, :])
     out = jax.lax.dot_general(hid, w2, (((1,), (0,)), ((), ())),
+                              precision=_HIGHEST,
                               preferred_element_type=jnp.float32)
     o_ref[0] = (out[:, 0] + b2[0]).astype(o_ref.dtype)
 

@@ -6,37 +6,44 @@ multi-pod — and records memory analysis, cost analysis, and the collective
 schedule for the roofline report. No arrays are ever allocated: parameters,
 optimizer state, batches, and caches are ShapeDtypeStructs.
 
-Run:  PYTHONPATH=src python -m repro.launch.dryrun --out results/dryrun.jsonl
+Run:  PYTHONPATH=src JAX_PLATFORMS=cpu python -m repro.launch.dryrun \
+          --out results/dryrun.jsonl
+
+The placeholder devices are CPU host devices: 512 by default,
+``REPRO_DRYRUN_DEVICES`` to shrink them (tests use 8).
 """
+import argparse
+import dataclasses
+import json
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+import re
+import time
+import traceback
 
-# tests shrink the placeholder device count (set before jax import)
-if os.environ.get("REPRO_DRYRUN_DEVICES"):
-    os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count="
-                               + os.environ["REPRO_DRYRUN_DEVICES"])
+import jax
+from jax.sharding import NamedSharding, PartitionSpec as P
 
-import argparse          # noqa: E402
-import dataclasses       # noqa: E402
-import json              # noqa: E402
-import time              # noqa: E402
-import traceback         # noqa: E402
+from repro.analysis.hlo import collective_bytes
+from repro.analysis.roofline import roofline_terms
+from repro.configs import ARCH_IDS, SHAPES, cell_is_applicable, get_config
+from repro.distributed.sharding import (FSDP_AXES, axis_rules, batch_specs,
+                                        cache_specs, param_specs)
+from repro.launch.inputs import input_specs
+from repro.launch.mesh import make_production_mesh, make_test_mesh
+from repro.models.model import decode_step, params_shape, prefill
+from repro.train.optimizer import make_optimizer
+from repro.train.step import make_train_step
 
-import jax               # noqa: E402
-from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
+_COUNT_FLAG = "--xla_force_host_platform_device_count"
 
-from repro.analysis.hlo import collective_bytes              # noqa: E402
-from repro.analysis.roofline import roofline_terms           # noqa: E402
-from repro.configs import (ARCH_IDS, SHAPES, cell_is_applicable,  # noqa: E402
-                           get_config)
-from repro.distributed.sharding import (FSDP_AXES, axis_rules,  # noqa: E402
-                                        batch_specs, cache_specs,
-                                        param_specs)
-from repro.launch.inputs import input_specs                   # noqa: E402
-from repro.launch.mesh import make_production_mesh            # noqa: E402
-from repro.models.model import decode_step, params_shape, prefill  # noqa: E402
-from repro.train.optimizer import make_optimizer              # noqa: E402
-from repro.train.step import make_train_step                  # noqa: E402
+
+def set_host_device_count(n: int) -> None:
+    """Give the CPU backend ``n`` placeholder devices. The caller's other
+    ``XLA_FLAGS`` are kept; only a count already there is replaced. Takes
+    effect only before the first JAX backend use in this process."""
+    flags = re.sub(rf"{_COUNT_FLAG}=\S*", "",
+                   os.environ.get("XLA_FLAGS", "")).split()
+    os.environ["XLA_FLAGS"] = " ".join(flags + [f"{_COUNT_FLAG}={n}"])
 
 
 def _ns(mesh, spec_tree):
@@ -287,14 +294,14 @@ def main() -> None:
     ap.add_argument("--test-mesh", action="store_true",
                     help="scaled-down meshes (REPRO_DRYRUN_DEVICES=8)")
     args = ap.parse_args()
+    set_host_device_count(int(os.environ.get("REPRO_DRYRUN_DEVICES", 512)))
 
     archs = list(ARCH_IDS) if args.arch == "all" else args.arch.split(",")
     shapes = list(SHAPES) if args.shape == "all" else args.shape.split(",")
 
     if args.test_mesh:
-        meshes = {"single": jax.make_mesh((2, 2), ("data", "model")),
-                  "multi": jax.make_mesh((2, 2, 2),
-                                         ("pod", "data", "model"))}
+        meshes = {"single": make_test_mesh(2, 2),
+                  "multi": make_test_mesh(2, 2, pod=2)}
     else:
         meshes = {"single": make_production_mesh(multi_pod=False),
                   "multi": make_production_mesh(multi_pod=True)}

@@ -4,6 +4,12 @@
 importing this module never touches jax device state; the dry-run sets
 XLA_FLAGS=--xla_force_host_platform_device_count=512 before first jax use.
 
+Every mesh here has Auto axes. The LM stack shards by logical-axis
+constraints (``distributed.sharding.shard``) and leaves the rest to the
+compiler's propagation; ``jax.make_mesh`` defaults to Explicit axes, under
+which every gather and sharded contraction would need its output sharding
+spelled out.
+
 XLA flags we deploy with on real TPU pods (latency-hiding scheduler /
 collective-compute overlap) are recorded here so the launcher and the
 EXPERIMENTS.md §Perf notes share one source of truth.
@@ -11,6 +17,7 @@ EXPERIMENTS.md §Perf notes share one source of truth.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 # flags enabling compute/collective overlap on TPU deployments; they do not
 # change CPU dry-run results but are part of the shipped launch config.
@@ -30,15 +37,19 @@ ICI_BW = 50e9                  # B/s per link (~uni-directional per axis)
 HBM_PER_CHIP_GB = 16.0
 
 
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with Auto axes (see the module docstring)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_test_mesh(n_data: int = 2, n_model: int = 2, *, pod: int = 0):
     """Small mesh for unit tests (8 forced host devices)."""
     if pod:
-        return jax.make_mesh((pod, n_data, n_model),
-                             ("pod", "data", "model"))
-    return jax.make_mesh((n_data, n_model), ("data", "model"))
+        return make_mesh((pod, n_data, n_model), ("pod", "data", "model"))
+    return make_mesh((n_data, n_model), ("data", "model"))

@@ -1,1 +1,2 @@
-from repro.utils.misc import GB, MB, ceil_div, round_up, tree_bytes, stable_hash
+from repro.utils.misc import (GB, MB, ceil_div, enable_compilation_cache,
+                              round_up, stable_hash, tree_bytes)

@@ -13,7 +13,9 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def run_py(script: str, devices: int = 8, timeout: int = 600):
-    env = dict(os.environ,
+    # JAX_PLATFORMS=cpu: the forced host devices are CPU devices, and a
+    # child must never reach for an accelerator its parent may hold
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS=f"--xla_force_host_platform_device_count={devices}",
                PYTHONPATH=os.path.join(REPO, "src"))
     return subprocess.run([sys.executable, "-c", script], env=env,
@@ -26,12 +28,13 @@ import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import get_config
 from repro.distributed.sharding import axis_rules, param_specs, batch_specs
+from repro.launch.mesh import make_test_mesh
 from repro.models.model import init_params
 from repro.train.optimizer import make_optimizer
 from repro.train.step import make_train_step
 
 cfg = get_config("granite-3-2b").reduced()
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+mesh = make_test_mesh(2, 2)
 params = init_params(cfg, jax.random.PRNGKey(0))
 opt = make_optimizer("adamw")
 opt_state = opt.init(params)
@@ -79,7 +82,7 @@ def test_compressed_psum_shard_map():
     r = run_py("""
 import jax, jax.numpy as jnp, numpy as np
 from functools import partial
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 from repro.train.compression import compressed_psum
 
@@ -131,7 +134,7 @@ def test_dryrun_small_grid():
     """Scaled-down dry-run: one arch, train+decode, single+multi mesh."""
     with tempfile.TemporaryDirectory() as td:
         out = os.path.join(td, "dry.jsonl")
-        env = dict(os.environ, REPRO_DRYRUN_DEVICES="8",
+        env = dict(os.environ, JAX_PLATFORMS="cpu", REPRO_DRYRUN_DEVICES="8",
                    PYTHONPATH=os.path.join(REPO, "src"))
         r = subprocess.run(
             [sys.executable, "-m", "repro.launch.dryrun", "--test-mesh",

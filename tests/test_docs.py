@@ -170,7 +170,7 @@ def _help_text(script: pathlib.Path) -> str:
     proc = subprocess.run(
         [sys.executable, str(script), "--help"], cwd=REPO,
         env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-             "HOME": "/tmp"},
+             "HOME": "/tmp", "JAX_PLATFORMS": "cpu"},
         capture_output=True, text=True, timeout=120)
     assert proc.returncode == 0, (
         f"{script.name} --help exited {proc.returncode}:\n{proc.stderr}")
