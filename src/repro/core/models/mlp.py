@@ -86,8 +86,9 @@ def _adam_steps(params, m, v, step0, xn, yn, mask, lr, n_steps):
             params, mhat, vhat)
         return (params, m, v, t), None
 
-    (params, m, v, t), _ = jax.lax.scan(
-        body, (params, m, v, step0), None, length=n_steps)
+    with jax.named_scope("adam_scan"):
+        (params, m, v, t), _ = jax.lax.scan(
+            body, (params, m, v, step0), None, length=n_steps)
     return params, m, v, t
 
 

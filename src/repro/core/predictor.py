@@ -45,7 +45,6 @@ import collections
 import dataclasses
 import functools
 import math
-import time
 
 import jax
 import jax.numpy as jnp
@@ -290,14 +289,15 @@ def _pool_model_preds(models: tuple[str, ...], cfg: SizeyConfig,
     cols = []
     for i, m in enumerate(models):
         mod = MODEL_MODULES[m]
-        if m == "knn":
-            cols.append(mod.predict_batch(states[i], xb, k=cfg.knn_k))
-        elif m == "mlp":
-            TRACE_COUNTS["mlp_pallas" if use_pallas else "mlp_jnp"] += 1
-            cols.append(mod.predict_batch(states[i], xb,
-                                          use_pallas=use_pallas))
-        else:
-            cols.append(mod.predict_batch(states[i], xb))
+        with jax.named_scope(m):
+            if m == "knn":
+                cols.append(mod.predict_batch(states[i], xb, k=cfg.knn_k))
+            elif m == "mlp":
+                TRACE_COUNTS["mlp_pallas" if use_pallas else "mlp_jnp"] += 1
+                cols.append(mod.predict_batch(states[i], xb,
+                                              use_pallas=use_pallas))
+            else:
+                cols.append(mod.predict_batch(states[i], xb))
     return jnp.stack(cols)
 
 
@@ -317,7 +317,7 @@ def _fused_predict(models: tuple[str, ...], cfg: SizeyConfig, ttf: float,
     [allocation, agg, offset, offset_idx, best_model, preds, raq, weights].
     """
 
-    def fn(states, xc, acc, alpha_eff, offset, off_idx):
+    def predict_fn(states, xc, acc, alpha_eff, offset, off_idx):
         TRACE_COUNTS["predict"] += 1
         xb, caps = xc[:, :-1], xc[:, -1]
         preds = _pool_model_preds(models, cfg, use_pallas, states, xb)
@@ -331,9 +331,10 @@ def _fused_predict(models: tuple[str, ...], cfg: SizeyConfig, ttf: float,
                               jnp.argmax(raq).astype(jnp.float32)])
             return jnp.concatenate([head, p, raq, weights])
 
-        return jax.vmap(one, in_axes=(1, 0))(preds, caps)
+        with jax.named_scope("combine"):
+            return jax.vmap(one, in_axes=(1, 0))(preds, caps)
 
-    return jax.jit(fn)
+    return jax.jit(predict_fn)
 
 
 @functools.lru_cache(maxsize=None)
@@ -348,19 +349,20 @@ def _fused_observe_all(models: tuple[str, ...], cfg: SizeyConfig,
                    log_actual, log_runtime, log_mask, log_model_preds):
         TRACE_COUNTS["update" if incremental else "fit"] += 1
         rng = jax.random.PRNGKey(seed)
-        if incremental:
-            new_states = tuple(
-                MODEL_MODULES[m].update(states[i], xs, ys, mask, new_idx,
-                                        rng, cfg)
-                for i, m in enumerate(models))
-        else:
-            new_states = tuple(MODEL_MODULES[m].fit(xs, ys, mask, rng, cfg)
-                               for m in models)
+        new_states = []
+        for i, m in enumerate(models):
+            mod = MODEL_MODULES[m]
+            with jax.named_scope(m):
+                new_states.append(
+                    mod.update(states[i], xs, ys, mask, new_idx, rng, cfg)
+                    if incremental else mod.fit(xs, ys, mask, rng, cfg))
+        new_states = tuple(new_states)
         insample = _pool_model_preds(models, cfg, use_pallas, new_states, xs)
-        cache = _decision_cache_core(
-            cfg.strategy, cfg.alpha, cfg.beta, ttf, cfg.adaptive_alpha,
-            insample, ys, runtimes, mask, log_agg, log_actual, log_runtime,
-            log_mask, log_model_preds)
+        with jax.named_scope("combine"):
+            cache = _decision_cache_core(
+                cfg.strategy, cfg.alpha, cfg.beta, ttf, cfg.adaptive_alpha,
+                insample, ys, runtimes, mask, log_agg, log_actual,
+                log_runtime, log_mask, log_model_preds)
         return new_states, insample, cache
 
     return jax.jit(observe_fn)
@@ -380,10 +382,11 @@ def _fused_refresh_all(models: tuple[str, ...], cfg: SizeyConfig,
                    log_runtime, log_mask, log_model_preds):
         TRACE_COUNTS["refresh"] += 1
         insample = _pool_model_preds(models, cfg, use_pallas, states, xs)
-        cache = _decision_cache_core(
-            cfg.strategy, cfg.alpha, cfg.beta, ttf, cfg.adaptive_alpha,
-            insample, ys, runtimes, mask, log_agg, log_actual, log_runtime,
-            log_mask, log_model_preds)
+        with jax.named_scope("combine"):
+            cache = _decision_cache_core(
+                cfg.strategy, cfg.alpha, cfg.beta, ttf, cfg.adaptive_alpha,
+                insample, ys, runtimes, mask, log_agg, log_actual,
+                log_runtime, log_mask, log_model_preds)
         return insample, cache
 
     return jax.jit(refresh_fn)
@@ -436,7 +439,6 @@ class SizeyPredictor:
         # refit so every fit runs at the pool's current padded shape)
         self._next_fit_at: dict[tuple[str, str], int] = {}
         self._fit_cap: dict[tuple[str, str], int] = {}
-        self.train_times_s: list[float] = []
         self.model_select_counts = np.zeros(len(self.models), np.int64)
 
     # ------------------------------------------------------------- predict
@@ -614,7 +616,6 @@ class SizeyPredictor:
         if pool.count < self.cfg.min_history:
             return
 
-        t0 = time.perf_counter()
         serial = self._fit_serial.get(key, 0)
         seed = (stable_hash(f"{key}") + serial + self.cfg.seed) % (2**31)
         if not self.fused:
@@ -622,7 +623,6 @@ class SizeyPredictor:
         else:
             self._maybe_refit(key, pool, seed)
         self._fit_serial[key] = serial + 1
-        self.train_times_s.append(time.perf_counter() - t0)
 
     def observe_batch(self, observations) -> None:
         """Observe a wave of simultaneous completions in ONE fused observe
@@ -652,27 +652,26 @@ class SizeyPredictor:
         for key, obs_list in groups.items():
             pool = self.db.pool(*key)
             c0 = pool.count
-            for decision, peak, rt, attempts, workflow in obs_list:
-                self.db.add(TaskRecord(key[0], key[1], decision.features,
-                                       float(peak), float(rt), attempts,
-                                       workflow))
-                if decision.source == "model":
-                    self.db.add_log(key[0], key[1], decision.model_preds,
-                                    decision.agg_pred_gb, float(peak),
-                                    float(rt))
+            with _span("history/append", n=len(obs_list)):
+                for decision, peak, rt, attempts, workflow in obs_list:
+                    self.db.add(TaskRecord(key[0], key[1], decision.features,
+                                           float(peak), float(rt), attempts,
+                                           workflow))
+                    if decision.source == "model":
+                        self.db.add_log(key[0], key[1], decision.model_preds,
+                                        decision.agg_pred_gb, float(peak),
+                                        float(rt))
             # how many of the sequential observes would have refit: record
             # j (1-based) fits iff c0 + j >= min_history
             n = len(obs_list)
             m = n - max(0, min(self.cfg.min_history - c0 - 1, n))
             if m <= 0:
                 continue
-            t0 = time.perf_counter()
             serial = self._fit_serial.get(key, 0)
             seed = (stable_hash(f"{key}") + serial + (m - 1)
                     + self.cfg.seed) % (2**31)
             self._maybe_refit(key, pool, seed)
             self._fit_serial[key] = serial + m
-            self.train_times_s.append(time.perf_counter() - t0)
 
     def warm_start(self) -> None:
         """Refit every pool restored from a JSONL checkpoint so prediction
@@ -793,14 +792,14 @@ class SizeyPredictor:
                 pool.count - 1, seed,
                 pool.log_agg, pool.log_actual, pool.log_runtime,
                 pool.log_mask, pool.log_model_preds)
-        self.states[key] = states
-        self._cache[key] = cache
-        self._pview[key] = tuple(
-            s._replace(**{f: None for f in MODEL_MODULES[m].PREDICT_DROP})
-            if MODEL_MODULES[m].PREDICT_DROP else s
-            for m, s in zip(self.models, states))
-        pool.insample_preds = insample
-        jax.block_until_ready(insample)
+            self.states[key] = states
+            self._cache[key] = cache
+            self._pview[key] = tuple(
+                s._replace(**{f: None for f in MODEL_MODULES[m].PREDICT_DROP})
+                if MODEL_MODULES[m].PREDICT_DROP else s
+                for m, s in zip(self.models, states))
+            pool.insample_preds = insample
+            jax.block_until_ready(insample)
 
     def _observe_loop(self, key, pool, seed: int) -> None:
         """Pre-fusion reference: per-model fit/update dispatches plus an

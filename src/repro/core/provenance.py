@@ -346,17 +346,21 @@ class ProvenanceDB:
             with open(self.persist_path, "a") as f:
                 f.write(json.dumps(row) + "\n")
 
-    def add_aux(self, kind: str, payload: dict) -> None:
+    def add_aux(self, kind: str, payload: dict) -> int:
         """Append one subsystem-owned checkpoint row (``kind`` must not be
         ``"log"``/``"task"``). Collected into ``self.aux[kind]`` and
         persisted alongside the core rows, so e.g. temporal usage profiles
-        survive the same JSONL round-trip as the history they annotate."""
+        survive the same JSONL round-trip as the history they annotate.
+        Returns the bytes written (0 without a ``persist_path``)."""
         if kind in ("log", "task"):
             raise ValueError(f"aux kind {kind!r} collides with core rows")
         self.aux.setdefault(kind, []).append(payload)
-        if self.persist_path:
-            with open(self.persist_path, "a") as f:
-                f.write(json.dumps({"kind": kind, **payload}) + "\n")
+        if not self.persist_path:
+            return 0
+        line = json.dumps({"kind": kind, **payload}) + "\n"
+        with open(self.persist_path, "a") as f:
+            f.write(line)
+        return len(line)
 
     def history_size(self, task_type: str, machine: str) -> int:
         key = (task_type, machine)

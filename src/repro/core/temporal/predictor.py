@@ -264,26 +264,27 @@ class TemporalSizeyPredictor:
         observations of the wave through the inner ``observe_batch`` —
         one fused fit dispatch per pool."""
         obs = []
-        for decision, task, attempts in completions:
-            key = (decision.task_type, decision.machine)
-            profile = grid_profile(task.usage_curve, self.n_grid,
-                                   peak_gb=task.actual_peak_gb)
-            if self.k > 1:
-                profs = self._profiles.setdefault(key, [])
-                profs.append(profile)
-                del profs[:-PROFILE_WINDOW]       # bounded fit window
-                # bump the pool generation: the cached boundary fit is
-                # stale from here; the next boundaries() call refits once
-                self._gen[key] = self._gen.get(key, 0) + 1
-                self.db.add_aux(CURVE_KIND, {
-                    "task_type": key[0], "machine": key[1],
-                    "profile": [float(v) for v in profile]})
-                peaks = segment_peaks(profile, decision.boundaries)
-            else:
-                peaks = np.asarray([task.actual_peak_gb])
-            for d, seg_peak in zip(decision.seg_decisions, peaks):
-                obs.append((d, float(seg_peak), float(task.runtime_h),
-                            attempts, task.workflow))
+        with _span("history/append", n=len(completions)):
+            for decision, task, attempts in completions:
+                key = (decision.task_type, decision.machine)
+                profile = grid_profile(task.usage_curve, self.n_grid,
+                                       peak_gb=task.actual_peak_gb)
+                if self.k > 1:
+                    profs = self._profiles.setdefault(key, [])
+                    profs.append(profile)
+                    del profs[:-PROFILE_WINDOW]       # bounded fit window
+                    # bump the pool generation: the cached boundary fit is
+                    # stale from here; the next boundaries() call refits
+                    self._gen[key] = self._gen.get(key, 0) + 1
+                    self.db.add_aux(CURVE_KIND, {
+                        "task_type": key[0], "machine": key[1],
+                        "profile": [float(v) for v in profile]})
+                    peaks = segment_peaks(profile, decision.boundaries)
+                else:
+                    peaks = np.asarray([task.actual_peak_gb])
+                for d, seg_peak in zip(decision.seg_decisions, peaks):
+                    obs.append((d, float(seg_peak), float(task.runtime_h),
+                                attempts, task.workflow))
         self.predictor.observe_batch(obs)
 
     def observe(self, decision: TemporalDecision, task,

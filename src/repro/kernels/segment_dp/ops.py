@@ -74,24 +74,26 @@ def cost_matrix_jnp(P: jnp.ndarray) -> jnp.ndarray:
 def _fit_cuts_jit(P, *, k: int, use_pallas: bool = False,
                   interpret: bool = False):
     g = P.shape[1]
-    if use_pallas:
-        from repro.kernels.segment_dp.kernel import segment_cost_blocked
-        cost = segment_cost_blocked(P, interpret=interpret)
-    else:
-        cost = cost_matrix_jnp(P)
+    with jax.named_scope("segment_cost"):
+        if use_pallas:
+            from repro.kernels.segment_dp.kernel import segment_cost_blocked
+            cost = segment_cost_blocked(P, interpret=interpret)
+        else:
+            cost = cost_matrix_jnp(P)
 
-    dp0 = jnp.full(g + 1, jnp.inf, jnp.float32).at[0].set(0.0)
+    with jax.named_scope("segment_dp"):
+        dp0 = jnp.full(g + 1, jnp.inf, jnp.float32).at[0].set(0.0)
 
-    def dp_step(dp_prev, _):
-        cand = dp_prev[:, None] + cost                    # (g+1, g+1)
-        bk = jnp.argmin(cand, axis=0)                     # first index
-        return cand[bk, jnp.arange(g + 1)], bk
-    _, back = jax.lax.scan(dp_step, dp0, None, length=k)  # back: (k, g+1)
+        def dp_step(dp_prev, _):
+            cand = dp_prev[:, None] + cost                # (g+1, g+1)
+            bk = jnp.argmin(cand, axis=0)                 # first index
+            return cand[bk, jnp.arange(g + 1)], bk
+        _, back = jax.lax.scan(dp_step, dp0, None, length=k)  # (k, g+1)
 
-    def walk(j, s):                                       # s = k-1 .. 0
-        return back[s, j], j
-    _, cuts = jax.lax.scan(walk, jnp.asarray(g, back.dtype),
-                           jnp.arange(k - 1, -1, -1))
+        def walk(j, s):                                   # s = k-1 .. 0
+            return back[s, j], j
+        _, cuts = jax.lax.scan(walk, jnp.asarray(g, back.dtype),
+                               jnp.arange(k - 1, -1, -1))
     return cuts[::-1]                                     # ends, last == g
 
 

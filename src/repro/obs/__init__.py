@@ -1,11 +1,11 @@
-"""Unified observability plane (PR 9): metrics registry, span tracing,
-and prediction-quality telemetry.
+"""Unified observability plane: metrics registry, span tracing, and
+prediction-quality telemetry.
 
 One layer, three instruments, shared across predictor, engines, and the
 scheduler service:
 
-  * **Metrics registry** (:mod:`repro.obs.metrics`) — named counter /
-    gauge / histogram families with Prometheus-style text exposition
+  * **Metrics registry** (:mod:`repro.obs.metrics`) — named counter and
+    gauge families with Prometheus-style text exposition
     (:func:`scrape`). The process-global work counters the tests and the
     CI regression gates consume (``TRACE_COUNTS``, ``DISPATCH_COUNTS``,
     ``BOUNDARY_COUNTS``) are registry-backed :class:`CounterFamily`
@@ -14,10 +14,12 @@ scheduler service:
     :func:`scoped_counters` brackets a run so back-to-back simulations
     report independent counts without losing the process totals.
   * **Span tracing** (:mod:`repro.obs.trace`) — ``with span("predict",
-    pool=...)`` context managers on the hot paths, exported as
-    Chrome/Perfetto ``trace_event`` JSON so a cluster replay renders as
-    a flamegraph. A single ``None`` check when tracing is off; wall
-    clocks are read only while a collector is active.
+    pool=...)`` context managers on the hot paths, each linked to its
+    parent span, exported as Chrome/Perfetto ``trace_event`` JSON so a
+    cluster replay renders as a flamegraph, and mirrored as
+    ``jax.profiler`` annotations so a profiler trace shows them beside
+    the device operations. A single ``None`` check when tracing is off;
+    wall clocks are read only while a collector is active.
   * **Quality telemetry** (:mod:`repro.obs.quality`) — per-pool,
     virtual-clock-stamped prediction-quality samples (RAQ, selected
     model, dynamic offset, prequential under/over-prediction error,
@@ -27,25 +29,24 @@ scheduler service:
 Telemetry is side-effect-free by construction: no instrument consumes
 rng state, reorders events, or feeds back into sizing arithmetic, so
 every bitwise invariant (serial equivalence, kill-at-any-byte warm
-resume, policy A/B) holds with tracing on. The package depends on the
-stdlib only — it imports nothing from ``repro``, so every subsystem can
-import it without cycles.
+resume, policy A/B) holds with tracing on. The package imports the
+stdlib only (``jax`` once tracing starts) and nothing from ``repro``, so
+every subsystem can import it without cycles.
 """
-from repro.obs.metrics import (CounterFamily, Gauge, Histogram,
-                               MetricsRegistry, counter, default_registry,
-                               gauge, histogram, metrics_enabled, scrape,
-                               scoped_counters, set_metrics_enabled)
+from repro.obs.metrics import (CounterFamily, Gauge, MetricsRegistry,
+                               counter, default_registry, gauge, scrape,
+                               scoped_counters)
 from repro.obs.quality import (QUALITY_KIND, read_quality_rows,
                                summarize_pools, write_quality_csv)
-from repro.obs.trace import (TraceCollector, span, start_tracing,
-                             stop_tracing, tracing, tracing_active)
+from repro.obs.trace import (TraceCollector, async_span, span,
+                             start_tracing, stop_tracing, tracing,
+                             tracing_active)
 
 __all__ = [
-    "CounterFamily", "Gauge", "Histogram", "MetricsRegistry",
-    "counter", "default_registry", "gauge", "histogram",
-    "metrics_enabled", "scrape", "scoped_counters", "set_metrics_enabled",
+    "CounterFamily", "Gauge", "MetricsRegistry", "counter",
+    "default_registry", "gauge", "scrape", "scoped_counters",
     "QUALITY_KIND", "read_quality_rows", "summarize_pools",
     "write_quality_csv",
-    "TraceCollector", "span", "start_tracing", "stop_tracing", "tracing",
-    "tracing_active",
+    "TraceCollector", "async_span", "span", "start_tracing",
+    "stop_tracing", "tracing", "tracing_active",
 ]

@@ -1,7 +1,7 @@
-"""Metrics registry: named counter / gauge / histogram families with
+"""Metrics registry: named counter and gauge families with
 Prometheus-style text exposition.
 
-Design constraints (the PR 9 contract):
+Design constraints:
 
   * **Counters are always on.** The families absorbing the legacy
     process globals (``TRACE_COUNTS`` / ``DISPATCH_COUNTS`` /
@@ -9,12 +9,9 @@ Design constraints (the PR 9 contract):
     dozens of snapshot-before / diff-after call sites, so a
     :class:`CounterFamily` IS a ``collections.Counter`` — same bump
     cost, same duck type, zero behavioural change for existing
-    consumers. Disabling the registry never silences them.
-  * **Gauges and histograms are optional instruments.** ``Gauge.set``
-    is cold-path (scrape time) and always works; ``Histogram.observe``
-    sits on warm paths and becomes a single attribute check when the
-    registry is disabled (:func:`set_metrics_enabled`), so a disabled
-    registry costs ~zero on the 100k-task replay.
+    consumers.
+  * **Gauges are cold-path.** ``Gauge.set`` runs at scrape or report
+    time. Timings of warm paths are spans (:mod:`repro.obs.trace`).
   * **Scoping.** :func:`scoped_counters` brackets a run: inside the
     ``with``, every family counts from zero (independent measurements
     for back-to-back simulations); on exit the pre-scope counts are
@@ -27,13 +24,8 @@ from __future__ import annotations
 import collections
 import contextlib
 
-__all__ = ["CounterFamily", "Gauge", "Histogram", "MetricsRegistry",
-           "counter", "default_registry", "gauge", "histogram",
-           "metrics_enabled", "scrape", "scoped_counters",
-           "set_metrics_enabled"]
-
-# default latency-style bucket bounds (seconds), Prometheus convention
-DEFAULT_BUCKETS = (0.001, 0.005, 0.01, 0.05, 0.1, 0.5, 1.0, 5.0)
+__all__ = ["CounterFamily", "Gauge", "MetricsRegistry", "counter",
+           "default_registry", "gauge", "scrape", "scoped_counters"]
 
 
 class CounterFamily(collections.Counter):
@@ -60,7 +52,7 @@ class CounterFamily(collections.Counter):
 class Gauge:
     """A named family of instantaneous values, keyed by label pairs:
     ``gauge.set(3, tenant="genomics")``. Cold-path (set at scrape or
-    report time), so it ignores the enabled flag."""
+    report time)."""
 
     def __init__(self, name: str, help: str = ""):
         self.name = name
@@ -83,65 +75,13 @@ class Gauge:
         return lines
 
 
-class Histogram:
-    """Fixed-bucket cumulative histogram (Prometheus semantics:
-    ``_bucket{le=...}`` counts observations <= each bound, plus ``_sum``
-    / ``_count``). ``observe`` is warm-path: a no-op while the owning
-    registry is disabled."""
-
-    def __init__(self, name: str, help: str = "",
-                 buckets: tuple[float, ...] = DEFAULT_BUCKETS,
-                 registry: "MetricsRegistry | None" = None):
-        self.name = name
-        self.help = help
-        self.buckets = tuple(sorted(buckets))
-        self._registry = registry
-        self._counts = [0] * (len(self.buckets) + 1)   # +inf tail
-        self._sum = 0.0
-        self._n = 0
-
-    def observe(self, value: float) -> None:
-        reg = self._registry
-        if reg is not None and not reg.enabled:
-            return
-        value = float(value)
-        for i, bound in enumerate(self.buckets):
-            if value <= bound:
-                self._counts[i] += 1
-                break
-        else:
-            self._counts[-1] += 1
-        self._sum += value
-        self._n += 1
-
-    @property
-    def count(self) -> int:
-        return self._n
-
-    def expose(self) -> list[str]:
-        lines = [f"# HELP {self.name} {self.help}".rstrip(),
-                 f"# TYPE {self.name} histogram"]
-        cum = 0
-        for bound, n in zip(self.buckets, self._counts):
-            cum += n
-            lines.append(f'{self.name}_bucket{{le="{bound:g}"}} {cum}')
-        cum += self._counts[-1]
-        lines.append(f'{self.name}_bucket{{le="+Inf"}} {cum}')
-        lines.append(f"{self.name}_sum {self._sum:g}")
-        lines.append(f"{self.name}_count {self._n}")
-        return lines
-
-
 class MetricsRegistry:
     """Process registry of metric families, one exposition endpoint.
 
-    ``enabled`` gates the warm-path instruments (histograms) only;
-    counters always count (see module docstring) and gauges are
-    cold-path. Families are get-or-create by name, so re-imports and
-    repeated ``counter(...)`` calls share one instance."""
+    Families are get-or-create by name, so re-imports and repeated
+    ``counter(...)`` calls share one instance."""
 
-    def __init__(self, enabled: bool = False):
-        self.enabled = enabled
+    def __init__(self):
         self._families: dict[str, object] = {}
 
     def _get(self, name: str, factory):
@@ -160,15 +100,6 @@ class MetricsRegistry:
     def gauge(self, name: str, help: str = "") -> Gauge:
         fam = self._get(name, lambda: Gauge(name, help))
         if not isinstance(fam, Gauge):
-            raise TypeError(f"metric {name!r} already registered as "
-                            f"{type(fam).__name__}")
-        return fam
-
-    def histogram(self, name: str, help: str = "",
-                  buckets: tuple[float, ...] = DEFAULT_BUCKETS) -> Histogram:
-        fam = self._get(name,
-                        lambda: Histogram(name, help, buckets, registry=self))
-        if not isinstance(fam, Histogram):
             raise TypeError(f"metric {name!r} already registered as "
                             f"{type(fam).__name__}")
         return fam
@@ -200,23 +131,8 @@ def gauge(name: str, help: str = "") -> Gauge:
     return _DEFAULT.gauge(name, help)
 
 
-def histogram(name: str, help: str = "",
-              buckets: tuple[float, ...] = DEFAULT_BUCKETS) -> Histogram:
-    return _DEFAULT.histogram(name, help, buckets)
-
-
 def scrape() -> str:
     return _DEFAULT.scrape()
-
-
-def set_metrics_enabled(flag: bool) -> None:
-    """Toggle the warm-path instruments (histograms). Counters are
-    unaffected — the CI work-counter gates consume them unconditionally."""
-    _DEFAULT.enabled = bool(flag)
-
-
-def metrics_enabled() -> bool:
-    return _DEFAULT.enabled
 
 
 @contextlib.contextmanager

@@ -33,7 +33,7 @@ from typing import Callable
 
 from repro.core.provenance import read_jsonl_lines
 from repro.obs import metrics as _obs_metrics
-from repro.obs.trace import span as _span
+from repro.obs.trace import async_span as _async_span, span as _span
 from repro.workflow.cluster import ClusterEngine
 from repro.workflow.journal import WAL_KIND, Journal, recover_run
 from repro.workflow.simulator import SimResult
@@ -153,8 +153,7 @@ class SchedulerService:
         """Prometheus-style text exposition of the whole process: the
         per-tenant scheduler gauges refreshed from :meth:`stats`, plus
         every registry family (predictor dispatch/trace counters, boundary
-        fits, any enabled histograms) — one endpoint an operator can poll
-        while workflows run."""
+        fits) — one endpoint an operator can poll while workflows run."""
         reg = _obs_metrics.default_registry()
         for tenant, vals in self.stats().items():
             for stat, value in vals.items():
@@ -180,7 +179,7 @@ class SchedulerService:
                 f"({self._share_cap(t)} active workflows)")
 
     async def _admit_with_backoff(self, t: _Tenant) -> None:
-        with _span("service/admit", tenant=t.name):
+        with _async_span("service/admit", tenant=t.name):
             await self._admit_with_backoff_inner(t)
 
     async def _admit_with_backoff_inner(self, t: _Tenant) -> None:

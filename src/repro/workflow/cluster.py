@@ -1271,6 +1271,10 @@ class ClusterEngine:
         """Advance the engine by one event-drain + scheduling round.
         Returns False (and journals the run's ``end`` marker) once every
         task has an outcome."""
+        with _span("cluster/step", step=self._step_idx):
+            return self._step()
+
+    def _step(self) -> bool:
         if not self.queue and not self.running \
                 and self.pending_arrivals == 0:
             self._finish_journal()
@@ -1919,6 +1923,10 @@ class ClusterEngine:
         DAG in-degrees, recorded outcomes, all counters, and the failure
         rng states — everything :meth:`_restore_state` needs to rebuild a
         bitwise-identical engine mid-workflow."""
+        with _span("cluster/export_state", step=self._step_idx):
+            return self._export_state()
+
+    def _export_state(self) -> dict:
         state = {
             "step": self._step_idx, "clock": self.clock,
             "eseq": self._eseq, "qseq": self._qseq,

@@ -119,7 +119,8 @@ class Journal:
         of the step, after the step's provenance rows: that ordering is
         what lets :meth:`repair` truncate a crash back to the last step
         boundary."""
-        self.db.add_aux(WAL_KIND, rec)
+        with _span("journal/append", step=rec["step"]):
+            self.db.add_aux(WAL_KIND, rec)
 
     def end(self, *, step: int, n_outcomes: int) -> None:
         """Write the ``end`` marker; a journal without one is an
@@ -131,9 +132,9 @@ class Journal:
         """Write a compacted full-state engine snapshot row (everything
         ``ClusterEngine.export_state()`` serializes — indexes excluded:
         they rebuild deterministically on restore)."""
-        with _span("journal/snapshot", step=state["step"]):
-            self.db.add_aux(SNAP_KIND,
-                            {"step": state["step"], "state": state})
+        with _span("journal/snapshot", step=state["step"]) as sp:
+            sp.set(bytes=self.db.add_aux(
+                SNAP_KIND, {"step": state["step"], "state": state}))
 
     def maybe_snapshot(self, step_idx: int,
                        state_fn: Callable[[], dict]) -> None:

@@ -1,10 +1,13 @@
-"""Observability plane (PR 9): metrics registry back-compat, scoped
-counters, span tracing (null cost, determinism, Perfetto export),
-prediction-quality telemetry, journal interplay, and service scrape."""
+"""Observability plane: metrics registry back-compat, scoped counters,
+span tracing (null cost, parent links, profiler mirroring, determinism,
+Perfetto export), prediction-quality telemetry, journal interplay, and
+service scrape."""
 import asyncio
 import collections
 import json
 import os
+import subprocess
+import sys
 
 import pytest
 
@@ -13,6 +16,7 @@ from repro import obs
 from repro.baselines.sizey_method import SizeyMethod
 from repro.core.predictor import DISPATCH_COUNTS, TRACE_COUNTS
 from repro.core.temporal.predictor import BOUNDARY_COUNTS
+from repro.obs import trace as obs_trace
 from repro.obs.quality import (QUALITY_FIELDS, read_quality_rows,
                                summarize_pools)
 from repro.obs.trace import _NULL_SPAN
@@ -60,27 +64,6 @@ def test_gauge_set_get_expose():
     assert 'test_obs_gauge{tenant="b"} 7.5' in lines
 
 
-def test_histogram_gated_by_enabled_flag():
-    h = obs.histogram("test_obs_hist", "a histogram", buckets=(0.1, 1.0))
-    prev = obs.metrics_enabled()
-    try:
-        obs.set_metrics_enabled(False)
-        h.observe(0.05)
-        assert h.count == 0            # warm-path no-op while disabled
-        obs.set_metrics_enabled(True)
-        h.observe(0.05)
-        h.observe(0.5)
-        h.observe(2.0)
-        assert h.count == 3
-    finally:
-        obs.set_metrics_enabled(prev)
-    lines = h.expose()
-    assert 'test_obs_hist_bucket{le="0.1"} 1' in lines
-    assert 'test_obs_hist_bucket{le="1"} 2' in lines
-    assert 'test_obs_hist_bucket{le="+Inf"} 3' in lines
-    assert "test_obs_hist_count 3" in lines
-
-
 def test_scoped_counters_restores_process_totals():
     c = obs.counter("test_obs_scoped_total")
     c["x"] += 5
@@ -112,6 +95,195 @@ def test_span_is_null_singleton_when_off():
     assert obs.span("predict", k=3) is _NULL_SPAN
     with obs.span("predict"):          # still a working context manager
         pass
+
+
+def test_span_off_creates_no_annotation_and_obs_imports_no_jax(
+        monkeypatch):
+    made = []
+    monkeypatch.setattr(obs_trace, "_ANNOTATION",
+                        lambda name: made.append(name))
+    assert obs.span("cluster/step", step=0) is _NULL_SPAN
+    with obs.async_span("service/admit") as sp:
+        sp.set(bytes=1)                # a no-op on the null span
+    assert made == []
+    code = ("import sys, repro.obs; "
+            "sys.exit(int(any(m == 'jax' or m.startswith('jax.') "
+            "for m in sys.modules)))")
+    env = dict(os.environ, PYTHONPATH=os.pathsep.join(sys.path))
+    assert subprocess.run([sys.executable, "-c", code], env=env,
+                          timeout=60).returncode == 0
+
+
+def test_spans_link_to_their_parent():
+    with obs.tracing() as col:
+        with obs.span("root"):
+            with obs.span("child") as c:
+                with obs.span("leaf"):
+                    pass
+                c.set(bytes=7)
+            with obs.span("sibling"):
+                pass
+        with obs.span("second_root"):
+            pass
+    by = {s[0]: s for s in col.spans}
+    assert [s[0] for s in col.spans] == ["leaf", "child", "sibling", "root",
+                                         "second_root"]
+    assert len({s[4] for s in col.spans}) == 5
+    assert by["root"][5] is None and by["second_root"][5] is None
+    assert by["child"][5] == by["sibling"][5] == by["root"][4]
+    assert by["leaf"][5] == by["child"][4]
+    assert by["child"][3] == {"bytes": 7}
+    # the first four fields keep their meaning: name, start, dur, args
+    assert by["root"][1] <= by["child"][1] <= by["leaf"][1]
+    assert by["root"][2] >= by["child"][2] >= by["leaf"][2] >= 0
+    ev = {e["name"]: e for e in col.to_chrome_trace()["traceEvents"]}
+    assert ev["leaf"]["args"] == {"id": by["leaf"][4],
+                                  "parent": by["child"][4]}
+
+
+def test_interleaved_asyncio_tasks_nest_under_their_own_spans():
+    async def task(i, gate):
+        with obs.async_span("task", i=i):
+            await gate.wait()          # both tasks hold their span open
+            with obs.span("child", i=i):
+                await asyncio.sleep(0)
+
+    async def main():
+        gate = asyncio.Event()
+        jobs = [asyncio.create_task(task(i, gate)) for i in range(2)]
+        await asyncio.sleep(0)
+        with obs.span("main"):
+            gate.set()
+            await asyncio.gather(*jobs)
+
+    with obs.tracing() as col:
+        asyncio.run(main())
+    tasks = {s[3]["i"]: s for s in col.spans if s[0] == "task"}
+    children = [s for s in col.spans if s[0] == "child"]
+    assert len(tasks) == 2 and len(children) == 2
+    for c in children:
+        assert c[5] == tasks[c[3]["i"]][4]
+    # a task copies its context when created: no span was open then
+    assert all(t[5] is None for t in tasks.values())
+
+
+def _host_events(log_dir: str) -> list:
+    import glob
+    from jax.profiler import ProfileData
+    path = sorted(glob.glob(os.path.join(log_dir, "plugins", "profile", "*",
+                                         "*.xplane.pb")))[-1]
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                out.extend((e.name, int(e.start_ns), int(e.duration_ns))
+                           for e in line.events)
+    return out
+
+
+def test_spans_mirror_as_profiler_annotations(tmp_path):
+    import jax
+    import jax.numpy as jnp
+    f = jax.jit(lambda x: x @ x)
+    x = jnp.ones((64, 64))
+    f(x).block_until_ready()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        with obs.tracing() as col:
+            with obs.span("mirror/anchor"):
+                pass
+            for i in range(3):
+                with obs.span("mirror/step", i=i):
+                    with obs.span("mirror/work"):
+                        f(x).block_until_ready()
+            with obs.async_span("mirror/unmirrored"):
+                pass
+    finally:
+        jax.profiler.stop_trace()
+    host = [e for e in _host_events(str(tmp_path))
+            if e[0].startswith("mirror/")]
+    counts = collections.Counter(e[0] for e in host)
+    assert counts == {"mirror/anchor": 1, "mirror/step": 3,
+                      "mirror/work": 3}
+    # one anchor maps perf_counter_ns onto the profiler's clock
+    anchor = next(s for s in col.spans if s[0] == "mirror/anchor")
+    shift = next(e[1] for e in host if e[0] == "mirror/anchor") - anchor[1]
+    for name in ("mirror/step", "mirror/work"):
+        mine = sorted(s[1:3] for s in col.spans if s[0] == name)
+        theirs = sorted(e[1:] for e in host if e[0] == name)
+        for (start, dur), (pstart, pdur) in zip(mine, theirs):
+            assert abs(start + shift - pstart) < 100_000
+            assert abs(dur - pdur) < 100_000
+
+
+def test_observe_span_closes_after_block_until_ready(monkeypatch):
+    import jax
+    real = jax.block_until_ready
+
+    def waited(x):
+        with obs.span("test/block_until_ready"):
+            return real(x)
+    monkeypatch.setattr(jax, "block_until_ready", waited)
+    with obs.tracing() as col:
+        simulate(_small_trace(), SizeyMethod(machine_cap_gb=CAP))
+    observes = {s[4] for s in col.spans if s[0] == "observe"}
+    waits = {s[5] for s in col.spans if s[0] == "test/block_until_ready"}
+    assert observes and observes <= waits
+
+
+def _journaled_temporal_spans(tmp_path, tag):
+    trace = _small_trace()
+    path = str(tmp_path / f"{tag}.jsonl")
+
+    def factory(p):
+        return SizeyMethod(machine_cap_gb=CAP, persist_path=p, temporal_k=4)
+    with obs.tracing() as col:
+        res = run_journaled(trace, factory, path, snapshot_every=8,
+                            n_nodes=4)
+    return col, res, path
+
+
+def test_step_journal_and_history_span_counts_are_deterministic(tmp_path):
+    runs = [_journaled_temporal_spans(tmp_path, t) for t in ("a", "b")]
+    (col, _, path), (col_b, _, _) = runs
+    assert col.span_counts == col_b.span_counts
+    n = col.span_counts
+    steps = [s for s in col.spans if s[0] == "cluster/step"]
+    with open(path) as f:
+        rows = [json.loads(line) for line in f]
+    wal_steps = sum(r.get("kind") == "wal" and r.get("rec") == "step"
+                    for r in rows)
+    # one step span per engine step call; the last writes the end marker
+    assert n["journal/append"] == wal_steps == n["cluster/step"] - 1
+    assert n["cluster/export_state"] == n["journal/snapshot"] \
+        == wal_steps // 8
+    # per completion wave: one append of its curves (n tasks), then one
+    # of each pool's rows (k = 4 segment rows a task): never per task
+    waves = {s[4]: s[3]["n"] for s in col.spans
+             if s[0] == "engine/complete_wave"}
+    appends = collections.defaultdict(list)
+    for s in col.spans:
+        if s[0] == "history/append":
+            appends[s[5]].append(s[3]["n"])
+    assert set(appends) == set(waves)
+    for wave, n_tasks in waves.items():
+        assert appends[wave][0] == n_tasks
+        assert sum(appends[wave][1:]) == 4 * n_tasks
+        assert 1 <= len(appends[wave]) - 1 <= n_tasks
+    step_ids = {s[4] for s in steps}
+    for s in col.spans:
+        if s[0] in ("journal/append", "cluster/export_state",
+                    "journal/snapshot", "engine/complete_wave",
+                    "engine/sizing_wave"):
+            assert s[5] in step_ids, s
+    # each snapshot span carries the bytes of the row it wrote
+    with open(path) as f:
+        sizes = [len(line) for line in f if '"kind": "snap"' in line]
+    assert len(sizes) == n["journal/snapshot"]
+    assert [s[3]["bytes"] for s in col.spans
+            if s[0] == "journal/snapshot"] == sizes
 
 
 def test_tracing_scope_restores_previous_collector():
