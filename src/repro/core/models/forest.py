@@ -6,9 +6,17 @@ and do not vectorize on TPU. We replace them with oblivious regression trees
 
   * prediction is a bit-packed comparison + a 2^depth leaf-table gather,
     pure jnp, batchable over (trees × tasks);
-  * training is an exhaustive vectorized scan over candidate thresholds per
-    level (vmapped over candidates and over trees), with per-tree Poisson
-    bootstrap weights for ensemble diversity.
+  * training is an exhaustive search over candidate thresholds (16 masked
+    quantiles per feature), all trees at once, with per-tree Poisson
+    bootstrap weights for ensemble diversity. Each level's histogram (the
+    weighted count, sum and sum of squares of every child of every
+    candidate split, in every tree) is one dense masked accumulation: each
+    row's statistics, one-hot on its child segment, are added to all
+    (tree, candidate, segment) sums at once, row after row, in the
+    ``split_hist`` Pallas kernel. So a fit lowers with no scatter, whose
+    per-row updates serialize on an accelerator, and the sums keep a
+    scatter-add's row order bit for bit.
+    The last level's sums under the chosen split are the leaf sums.
 
 The incremental update keeps the grown structure and refreshes the leaf
 means from the full buffer (structure-frozen leaf refit) — O(CAP * trees),
@@ -22,6 +30,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.config import SizeyConfig
+from repro.kernels.split_hist import split_hist
 
 _EPS = 1e-9
 N_QUANTILES = 16
@@ -54,52 +63,69 @@ def _candidate_thresholds(xs: jnp.ndarray, mask: jnp.ndarray) -> jnp.ndarray:
     return jnp.nanquantile(xm, qs, axis=0).T  # (d, Q)
 
 
-def _split_sse(leaf: jnp.ndarray, go_right: jnp.ndarray, w: jnp.ndarray,
-               ys: jnp.ndarray, n_segments: int) -> jnp.ndarray:
-    """Weighted SSE of the partition induced by splitting every leaf."""
-    seg = leaf * 2 + go_right.astype(jnp.int32)
-    sw = jax.ops.segment_sum(w, seg, num_segments=n_segments)
-    swy = jax.ops.segment_sum(w * ys, seg, num_segments=n_segments)
-    swy2 = jax.ops.segment_sum(w * ys * ys, seg, num_segments=n_segments)
-    return jnp.sum(swy2 - swy * swy / jnp.maximum(sw, _EPS))
+def _hist(seg: jnp.ndarray, stats: jnp.ndarray, n_seg: int,
+          use_pallas: bool = False) -> jnp.ndarray:
+    """Per-segment sums of row statistics, in row order.
+
+    seg (CAP, T, C) int32: each row's segment under each tree and column;
+    stats (CAP, T, S). Returns (T, C, S, n_seg). Every (tree, column,
+    statistic, segment) sum adds each row's statistic where the segment is
+    the row's and 0.0 elsewhere, one row after another: the sums of a
+    scatter-add in row order, bit for bit, without its serialized updates.
+    The order matters: the split SSE below cancels, and candidates whose
+    SSE differ by less than the round-off would swap under another one.
+    ``use_pallas`` compiles the ``split_hist`` kernel (a TPU); elsewhere
+    the Pallas interpreter runs it, faster on a CPU than an XLA row loop.
+    """
+    with jax.named_scope("split_hist"):
+        cap, t, c = seg.shape
+        s = stats.shape[2]
+        # (tree, column) on the minor axis: the sums stay lane-dense
+        val = jnp.broadcast_to(stats.transpose(2, 0, 1)[..., None],
+                               (s, cap, t, c)).reshape(s, cap, t * c)
+        acc = split_hist(seg.reshape(cap, t * c), val, n_seg=n_seg,
+                         interpret=not use_pallas)
+        return acc.reshape(s, n_seg, t, c).transpose(2, 3, 0, 1)
 
 
-def _grow_tree(w: jnp.ndarray, xs: jnp.ndarray, ys: jnp.ndarray,
-               cands: jnp.ndarray, depth: int):
-    """Grow one oblivious tree with sample weights w. Returns (feat, thresh, leaf)."""
-    cap, d = xs.shape
-    q = cands.shape[1]
-    leaf = jnp.zeros((cap,), jnp.int32)
+def _grow(w: jnp.ndarray, xs: jnp.ndarray, ys: jnp.ndarray,
+          cands: jnp.ndarray, depth: int, use_pallas: bool):
+    """Grow T oblivious trees on sample weights w (T, CAP).
+
+    Returns feat (T, depth), thresh (T, depth) and the weighted count and
+    sum of ys in each leaf, (T, 2^depth) each.
+    """
+    t, cap = w.shape
+    # go-right bit of every candidate, index f * Q + q: shared by all trees
+    go = (xs[:, :, None] > cands[None]).reshape(cap, -1).astype(jnp.int32)
+    stats = jnp.stack([w, w * ys, w * ys * ys], axis=-1).swapaxes(0, 1)
+    leaf = jnp.zeros((cap, t), jnp.int32)
     feats, threshs = [], []
 
     for level in range(depth):
-        n_seg = 2 ** (level + 1)
-
-        def sse_for(f, qi):
-            return _split_sse(leaf, xs[:, f] > cands[f, qi], w, ys, n_seg)
-
-        fs = jnp.repeat(jnp.arange(d), q)
-        qs = jnp.tile(jnp.arange(q), d)
-        sses = jax.vmap(sse_for)(fs, qs)
-        best = jnp.argmin(sses)
-        bf, bq = fs[best], qs[best]
-        bt = cands[bf, bq]
+        h = _hist(leaf[:, :, None] * 2 + go[:, None, :], stats,
+                  2 ** (level + 1), use_pallas)                   # (T,C,3,K)
+        sw, swy, swy2 = h[:, :, 0], h[:, :, 1], h[:, :, 2]
+        sse = jnp.sum(swy2 - swy * swy / jnp.maximum(sw, _EPS), axis=-1)
+        best = jnp.argmin(sse, axis=1)  # ties go to the first candidate
+        bf = best // cands.shape[1]
         feats.append(bf)
-        threshs.append(bt)
-        leaf = leaf * 2 + (xs[:, bf] > bt).astype(jnp.int32)
+        threshs.append(cands[bf, best % cands.shape[1]])
+        leaf = leaf * 2 + go[:, best]
 
-    return jnp.stack(feats), jnp.stack(threshs), leaf
+    # the last level's sums under the chosen split are the leaf sums
+    pick = jnp.take_along_axis(h, best[:, None, None, None], axis=1)[:, 0]
+    return (jnp.stack(feats, axis=1), jnp.stack(threshs, axis=1),
+            pick[:, 0], pick[:, 1])
 
 
-def _leaf_means(leaf: jnp.ndarray, w: jnp.ndarray, ys: jnp.ndarray,
-                n_leaves: int, fallback: jnp.ndarray) -> jnp.ndarray:
-    sw = jax.ops.segment_sum(w, leaf, num_segments=n_leaves)
-    swy = jax.ops.segment_sum(w * ys, leaf, num_segments=n_leaves)
+def _leaf_means(sw: jnp.ndarray, swy: jnp.ndarray,
+                fallback: jnp.ndarray) -> jnp.ndarray:
     return jnp.where(sw > _EPS, swy / jnp.maximum(sw, _EPS), fallback)
 
 
 def fit(xs: jnp.ndarray, ys: jnp.ndarray, mask: jnp.ndarray, key,
-        cfg: SizeyConfig) -> ForestState:
+        cfg: SizeyConfig, use_pallas: bool = False) -> ForestState:
     t, depth = cfg.forest_trees, cfg.forest_depth
     cands = _candidate_thresholds(xs, mask)
     cands = jnp.nan_to_num(cands, nan=0.0)
@@ -108,14 +134,8 @@ def fit(xs: jnp.ndarray, ys: jnp.ndarray, mask: jnp.ndarray, key,
     # Poisson(1) bootstrap weights per tree (masked-out rows weigh 0)
     boot = jax.random.poisson(key, 1.0, (t, xs.shape[0])).astype(jnp.float32)
     boot = boot * mask[None, :]
-
-    def one_tree(w):
-        feat, thresh, leaf = _grow_tree(w, xs, ys, cands, depth)
-        vals = _leaf_means(leaf, w, ys, 2 ** depth, gmean)
-        return feat, thresh, vals
-
-    feat, thresh, vals = jax.vmap(one_tree)(boot)
-    return ForestState(feat, thresh, vals, gmean)
+    feat, thresh, sw, swy = _grow(boot, xs, ys, cands, depth, use_pallas)
+    return ForestState(feat, thresh, _leaf_means(sw, swy, gmean), gmean)
 
 
 def _leaf_index(feat: jnp.ndarray, thresh: jnp.ndarray,
@@ -128,17 +148,18 @@ def _leaf_index(feat: jnp.ndarray, thresh: jnp.ndarray,
 
 def update(state: ForestState, xs: jnp.ndarray, ys: jnp.ndarray,
            mask: jnp.ndarray, new_idx: jnp.ndarray, key,
-           cfg: SizeyConfig) -> ForestState:
+           cfg: SizeyConfig, use_pallas: bool = False) -> ForestState:
     """Structure-frozen leaf refresh from the full (unweighted) buffer."""
     depth = state.feat.shape[1]
     n = jnp.maximum(jnp.sum(mask), 1.0)
     gmean = jnp.sum(ys * mask) / n
 
-    def refresh(feat, thresh):
-        leaf = jax.vmap(lambda x: _leaf_index(feat, thresh, x))(xs)
-        return _leaf_means(leaf, mask, ys, 2 ** depth, gmean)
-
-    vals = jax.vmap(refresh)(state.feat, state.thresh)
+    leaf = jax.vmap(lambda f, th: jax.vmap(
+        lambda x: _leaf_index(f, th, x))(xs))(state.feat, state.thresh)
+    stats = jnp.broadcast_to(jnp.stack([mask, mask * ys], axis=-1)[:, None],
+                             (xs.shape[0], leaf.shape[0], 2))
+    sums = _hist(leaf.T[:, :, None], stats, 2 ** depth, use_pallas)[:, 0]
+    vals = _leaf_means(sums[:, 0], sums[:, 1], gmean)
     return ForestState(state.feat, state.thresh, vals, gmean)
 
 

@@ -352,10 +352,14 @@ def _fused_observe_all(models: tuple[str, ...], cfg: SizeyConfig,
         new_states = []
         for i, m in enumerate(models):
             mod = MODEL_MODULES[m]
+            # the forest's split histograms take a kernel where Pallas
+            # compiles, like the MLP's forward
+            kw = {"use_pallas": use_pallas} if m == "forest" else {}
             with jax.named_scope(m):
                 new_states.append(
-                    mod.update(states[i], xs, ys, mask, new_idx, rng, cfg)
-                    if incremental else mod.fit(xs, ys, mask, rng, cfg))
+                    mod.update(states[i], xs, ys, mask, new_idx, rng, cfg,
+                               **kw)
+                    if incremental else mod.fit(xs, ys, mask, rng, cfg, **kw))
         new_states = tuple(new_states)
         insample = _pool_model_preds(models, cfg, use_pallas, new_states, xs)
         with jax.named_scope("combine"):

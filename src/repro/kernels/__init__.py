@@ -16,4 +16,5 @@ routes here on TPU backends.
   ssd_scan        — Mamba2 chunked state-space scan (mamba2/zamba2 cells)
   knn             — blocked pairwise distances for Sizey's k-NN predictor
   ensemble_mlp    — fused (models x tasks) MLP forward for the Sizey pool
+  split_hist      — row-order split histograms of Sizey's forest fit
 """

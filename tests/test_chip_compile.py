@@ -84,11 +84,24 @@ def test_ensemble_mlp_kernel_compiles(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("cap", [1024, 16384])
+@pytest.mark.parametrize("cap", [1024, 4096, 16384])
 def test_fused_observe_full_fit_compiles(one_chip, cap):
     fn = _fused_observe_all(MODELS, CFG, 1.0, True, False)
     compiled = _compile(f"fused observe (full fit) cap={cap}", fn,
                         *_observe_args(one_chip, cap))
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("cap,d", [(4096, 1), (2048, 2)])
+def test_forest_fit_has_no_scatter(one_chip, cap, d):
+    # the split histograms run the row-order kernel: a scatter-add
+    # serializes its updates on a TPU
+    vec = _spec(one_chip, (cap,))
+    fn = jax.jit(MODEL_MODULES["forest"].fit, static_argnums=(4, 5))
+    compiled = _compile(f"forest fit cap={cap} d={d}", fn,
+                        _spec(one_chip, (cap, d)), vec, vec,
+                        _spec(one_chip, (2,), jnp.uint32), CFG, True)
+    assert " scatter(" not in compiled.as_text()
     assert "tpu_custom_call" in compiled.as_text()
 
 

@@ -1,4 +1,7 @@
 """Per-model-class tests: each regressor learns its designed relationship."""
+import dataclasses
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -91,6 +94,99 @@ def test_forest_update_refreshes_leaves():
     assert float(forest.predict(st2, jnp.asarray([4.0]))) == pytest.approx(10.0, abs=1.0)
     # structure unchanged
     np.testing.assert_array_equal(np.asarray(st.feat), np.asarray(st2.feat))
+
+
+def _scatter_fit(xs, ys, mask, key, cfg):
+    """Oracle: the forest grown with per-tree, per-candidate segment sums."""
+    t, depth = cfg.forest_trees, cfg.forest_depth
+    cands = jnp.nan_to_num(forest._candidate_thresholds(xs, mask), nan=0.0)
+    gmean = jnp.sum(ys * mask) / jnp.maximum(jnp.sum(mask), 1.0)
+    boot = jax.random.poisson(key, 1.0, (t, xs.shape[0])).astype(jnp.float32)
+    boot = boot * mask[None, :]
+    d, q = cands.shape
+
+    def sums(seg, w, n):
+        return [jax.ops.segment_sum(v, seg, num_segments=n)
+                for v in (w, w * ys, w * ys * ys)]
+
+    def tree(w):
+        leaf = jnp.zeros(xs.shape[0], jnp.int32)
+        feats, threshs = [], []
+        for level in range(depth):
+            sse = []
+            for f in range(d):
+                for qi in range(q):
+                    go = (xs[:, f] > cands[f, qi]).astype(jnp.int32)
+                    seg = leaf * 2 + go
+                    sw, swy, swy2 = sums(seg, w, 2 ** (level + 1))
+                    sse.append(jnp.sum(swy2 - swy * swy
+                                       / jnp.maximum(sw, forest._EPS)))
+            best = jnp.argmin(jnp.stack(sse))
+            f, qi = best // q, best % q
+            feats.append(f)
+            threshs.append(cands[f, qi])
+            leaf = leaf * 2 + (xs[:, f] > cands[f, qi]).astype(jnp.int32)
+        sw, swy, _ = sums(leaf, w, 2 ** depth)
+        vals = jnp.where(sw > forest._EPS,
+                         swy / jnp.maximum(sw, forest._EPS), gmean)
+        return jnp.stack(feats), jnp.stack(threshs), vals
+
+    return jax.vmap(tree)(boot)
+
+
+def _forest_pool(d, seed):
+    """A pool with masked rows holding junk, Poisson-zero rows, a discrete
+    first feature whose repeated quantiles give identical partitions, and
+    a capacity that is not a whole number of row-loop steps."""
+    rng = np.random.default_rng(seed)
+    cap, n = 200, 150
+    xs = rng.uniform(-50.0, 50.0, (cap, d)).astype(np.float32)
+    xs[:n, 0] = rng.integers(1, 7, n)
+    if d > 1:
+        xs[:n, 1] = rng.uniform(0.0, 4.0, n)
+    ys = rng.uniform(-100.0, 100.0, cap).astype(np.float32)
+    ys[:n] = (2.0 * xs[:n, 0] + 3.0 * (xs[:n, -1] > 2.0)
+              + rng.normal(0.0, 0.5, n))
+    mask = np.zeros((cap,), np.float32)
+    mask[:n] = 1.0
+    return jnp.asarray(xs), jnp.asarray(ys), jnp.asarray(mask)
+
+
+@pytest.mark.parametrize("d,depth", [(1, 1), (1, 3), (1, 4), (2, 1),
+                                     (2, 3), (2, 4)])
+def test_forest_split_hist_matches_segment_sum(monkeypatch, d, depth):
+    # blocks of 64 rows: the kernel's grid walks several, the last padded
+    monkeypatch.setattr(forest, "split_hist", functools.partial(
+        forest.split_hist, block_rows=64))
+    cfg = dataclasses.replace(CFG, forest_depth=depth)
+    xs, ys, mask = _forest_pool(d, seed=10 * d + depth)
+    key = jax.random.PRNGKey(depth)
+    cands = jnp.nan_to_num(forest._candidate_thresholds(xs, mask), nan=0.0)
+    assert len(np.unique(np.asarray(cands[0]))) < cands.shape[1]
+    w = jax.random.poisson(key, 1.0, (cfg.forest_trees, xs.shape[0]))
+    w = w.astype(jnp.float32) * mask
+    assert np.any((np.asarray(w) == 0) & (np.asarray(mask) > 0))
+    go = (xs[:, :, None] > cands[None]).reshape(xs.shape[0], -1)
+    go = go.astype(jnp.int32)
+    stats = jnp.stack([w, w * ys, w * ys * ys], axis=-1).swapaxes(0, 1)
+    rng = np.random.default_rng(depth)
+    for level in range(depth + 1):
+        n_seg = 2 ** (level + 1)
+        leaf = jnp.asarray(rng.integers(0, n_seg // 2, w.shape[::-1]),
+                           jnp.int32)
+        seg = leaf[:, :, None] * 2 + go[:, None, :]             # (CAP, T, C)
+        want = jax.vmap(jax.vmap(jax.vmap(
+            lambda sg, v: jax.ops.segment_sum(v, sg, num_segments=n_seg),
+            in_axes=(None, 1)), in_axes=(1, None)), in_axes=(1, 1))(
+                seg, stats)
+        got = forest._hist(seg, stats, n_seg)                   # (T, C, S, K)
+        # the row order of a scatter-add, so equal to the last bit
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    st = forest.fit(xs, ys, mask, key, cfg)
+    feat, thresh, vals = _scatter_fit(xs, ys, mask, key, cfg)
+    np.testing.assert_array_equal(np.asarray(st.feat), np.asarray(feat))
+    np.testing.assert_array_equal(np.asarray(st.thresh), np.asarray(thresh))
+    np.testing.assert_array_equal(np.asarray(st.leaf_vals), np.asarray(vals))
 
 
 @pytest.mark.parametrize("name", list(MODEL_MODULES))
