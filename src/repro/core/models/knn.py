@@ -58,6 +58,13 @@ def predict_batch(state: KNNState, xs: jnp.ndarray, *,
     return jax.vmap(lambda x: predict(state, x, k=k))(xs)
 
 
+def pairs(n_queries: int, n_rows: int) -> int:
+    """Distances ``predict_batch`` evaluates for ``n_queries`` query rows
+    against a state buffer of ``n_rows`` rows: every query, masked or not,
+    against every buffer row."""
+    return n_queries * n_rows
+
+
 def predict(state: KNNState, x: jnp.ndarray, *, k: int = 5) -> jnp.ndarray:
     d2 = jnp.sum(((state.xs - x[None, :]) / state.scale[None, :]) ** 2, -1)
     d2 = jnp.where(state.mask > 0, d2, jnp.inf)

@@ -709,7 +709,7 @@ class SizeyPredictor:
                 self._refit_fused(key, pool, seed, mask=jnp.asarray(trunc))
                 fn = _fused_refresh_all(self.models, self.cfg, self.ttf,
                                         self.use_pallas)
-                DISPATCH_COUNTS["refresh_pool"] += 1
+                self._count_dispatch("refresh_pool", pool)
                 insample, cache = fn(
                     self.states[key], pool.xs, pool.ys, pool.runtimes,
                     pool.mask, pool.log_agg, pool.log_actual,
@@ -745,8 +745,9 @@ class SizeyPredictor:
             return
         fn = _fused_refresh_all(self.models, self.cfg, self.ttf,
                                 self.use_pallas)
-        DISPATCH_COUNTS["refresh_pool"] += 1
-        with _span("refresh", pool=f"{key[0]}@{key[1]}", n=pool.count):
+        self._count_dispatch("refresh_pool", pool)
+        with _span("refresh", pool=f"{key[0]}@{key[1]}", n=pool.count,
+                   cap=pool.cap):
             insample, cache = fn(self.states[key], pool.xs, pool.ys,
                                  pool.runtimes, pool.mask, pool.log_agg,
                                  pool.log_actual, pool.log_runtime,
@@ -754,6 +755,14 @@ class SizeyPredictor:
             self._cache[key] = cache
             pool.insample_preds = insample
             jax.block_until_ready(insample)
+
+    def _count_dispatch(self, kind: str, pool) -> None:
+        """Count one observe or refresh launch, and the distances its
+        in-sample k-NN evaluates: the pool's buffer against itself."""
+        DISPATCH_COUNTS[kind] += 1
+        if "knn" in self.models:
+            DISPATCH_COUNTS["knn_pairs"] += MODEL_MODULES["knn"].pairs(
+                pool.cap, pool.cap)
 
     def _note_fit(self, key, pool) -> None:
         self._fit_cap[key] = pool.cap
@@ -788,8 +797,9 @@ class SizeyPredictor:
         incremental = key in self.states and self.cfg.incremental
         fn = _fused_observe_all(self.models, self.cfg, self.ttf,
                                 self.use_pallas, incremental)
-        DISPATCH_COUNTS["observe_pool"] += 1
-        with _span("observe", pool=f"{key[0]}@{key[1]}", n=pool.count):
+        self._count_dispatch("observe_pool", pool)
+        with _span("observe", pool=f"{key[0]}@{key[1]}", n=pool.count,
+                   cap=pool.cap):
             states, insample, cache = fn(
                 self.states[key] if incremental else None, pool.xs, pool.ys,
                 pool.runtimes, pool.mask if mask is None else mask,

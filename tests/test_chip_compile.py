@@ -90,6 +90,9 @@ def test_fused_observe_full_fit_compiles(one_chip, cap):
     compiled = _compile(f"fused observe (full fit) cap={cap}", fn,
                         *_observe_args(one_chip, cap))
     assert "tpu_custom_call" in compiled.as_text()
+    # the in-sample k-NN never holds its CAP x CAP distances (1 GiB alone
+    # at CAP 16384): the compiler fuses them into the top-k
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**30
 
 
 @pytest.mark.parametrize("cap,d", [(4096, 1), (2048, 2)])

@@ -1,6 +1,7 @@
 """Per-model-class tests: each regressor learns its designed relationship."""
 import dataclasses
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -59,6 +60,66 @@ def test_knn_ignores_masked_rows():
     ys = ys.at[20].set(1e9)  # poison a masked row
     st = knn.fit(xs, ys, mask, KEY, CFG)
     assert float(knn.predict(st, jnp.asarray([4.0]), k=5)) < 10.0
+
+
+def _knn_pool(cap, d, n, seed):
+    """A seeded pool of ``n`` valid rows in a ``cap``-row buffer; the
+    masked rows hold junk that must not leak into any prediction."""
+    rng = np.random.default_rng(seed)
+    xs = rng.uniform(0.1, 8.0, (cap, d)).astype(np.float32)
+    ys = (xs.sum(1) + rng.normal(0.0, 0.3, cap)).astype(np.float32)
+    mask = np.zeros(cap, np.float32)
+    mask[rng.permutation(cap)[:n]] = 1.0
+    ys[mask == 0] = 1e9
+    return jnp.asarray(xs), jnp.asarray(ys), jnp.asarray(mask)
+
+
+@pytest.mark.parametrize("d", [1, 2])
+def test_knn_predict_batch_matches_reference(d):
+    from chipbench.harness import reference
+    xs, ys, mask = _knn_pool(512, d, 300, seed=d)
+    st = knn.fit(xs, ys, mask, KEY, CFG)
+    got = jax.jit(lambda q: knn.predict_batch(st, q, k=CFG.knn_k))(xs)
+    ref = reference.knn_predict(xs, ys, mask, xs, CFG.knn_k)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref), rtol=1e-6)
+    assert float(jnp.max(got)) < 1e3           # no masked row leaked
+
+
+@pytest.mark.parametrize("queries,cap", [(128, 128), (16, 512)])
+def test_knn_pairs_is_what_the_top_k_reads(queries, cap):
+    """``knn.pairs`` counts the distances the traced program selects from:
+    the ``top_k`` operand is one distance per (query, buffer row)."""
+    xs, ys, mask = _knn_pool(cap, 1, cap // 2, seed=cap)
+    st = knn.fit(xs, ys, mask, KEY, CFG)
+    jaxpr = jax.make_jaxpr(
+        lambda q: knn.predict_batch(st, q, k=CFG.knn_k))(xs[:queries])
+    (shape,) = [e.invars[0].aval.shape for e in jaxpr.jaxpr.eqns
+                if e.primitive.name == "top_k"]
+    assert shape == (queries, cap)
+    assert math.prod(shape) == knn.pairs(queries, cap)
+
+
+@pytest.mark.parametrize("refit_growth,span", [(0.0, "observe"),
+                                               (0.5, "refresh")])
+def test_knn_pairs_count_and_span_cap(refit_growth, span):
+    from repro import obs
+    from repro.core.predictor import DISPATCH_COUNTS, SizeyPredictor
+    cfg = dataclasses.replace(CFG, mlp_train_steps=8, hpo=False,
+                              refit_growth=refit_growth)
+    pred = SizeyPredictor(cfg, default_machine_cap_gb=64.0)
+
+    def complete(x):
+        d = pred.predict("t", "m", [x], 8.0)
+        pred.observe(d, 1.0 + 0.1 * x, 0.1)
+    for i in range(10):
+        complete(1.0 + i)
+    pool = pred.db.pool("t", "m")
+    with obs.scoped_counters(DISPATCH_COUNTS) as dc, obs.tracing() as col:
+        complete(2.5)
+        assert dc[span + "_pool"] == 1
+        assert dc["knn_pairs"] == pool.cap * pool.cap == 128 * 128
+    (args,) = [s[3] for s in col.spans if s[0] == span]
+    assert args["n"] == 11 and args["cap"] == 128
 
 
 def test_mlp_learns_quadratic():
