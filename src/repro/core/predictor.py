@@ -278,19 +278,28 @@ def _jit_combine(strategy: str, alpha: float, beta: float, ttf: float,
 
 # ------------------------------------------------------------------- fused
 def _pool_model_preds(models: tuple[str, ...], cfg: SizeyConfig,
-                      use_pallas: bool, states, xb):
-    """All models' predictions over a (K, d) feature block -> (N, K).
+                      use_pallas: bool, states, xb, *, insample=False):
+    """All models' predictions over a (K, d) feature block ->
+    ((N, K), knn_fell_back).
 
     The model states are heterogeneous pytrees, so the "vmap over models"
     of the paper's loop is realized as compiler-level fusion: each model's
     batched forward is emitted into ONE XLA program (one dispatch), with the
     MLP routed through the fused Pallas ensemble kernel on accelerators.
+    ``insample``: ``xb`` is the pool's buffer, one query per row of the
+    states' buffers, so the k-NN takes its in-sample path, whose fallback
+    flag is returned.
     """
     cols = []
+    fell_back = jnp.bool_(False)
     for i, m in enumerate(models):
         mod = MODEL_MODULES[m]
         with jax.named_scope(m):
-            if m == "knn":
+            if m == "knn" and insample:
+                col, fell_back = mod.insample_batch(states[i], xb,
+                                                    k=cfg.knn_k)
+                cols.append(col)
+            elif m == "knn":
                 cols.append(mod.predict_batch(states[i], xb, k=cfg.knn_k))
             elif m == "mlp":
                 TRACE_COUNTS["mlp_pallas" if use_pallas else "mlp_jnp"] += 1
@@ -298,7 +307,7 @@ def _pool_model_preds(models: tuple[str, ...], cfg: SizeyConfig,
                                               use_pallas=use_pallas))
             else:
                 cols.append(mod.predict_batch(states[i], xb))
-    return jnp.stack(cols)
+    return jnp.stack(cols), fell_back
 
 
 @functools.lru_cache(maxsize=None)
@@ -320,7 +329,7 @@ def _fused_predict(models: tuple[str, ...], cfg: SizeyConfig, ttf: float,
     def predict_fn(states, xc, acc, alpha_eff, offset, off_idx):
         TRACE_COUNTS["predict"] += 1
         xb, caps = xc[:, :-1], xc[:, -1]
-        preds = _pool_model_preds(models, cfg, use_pallas, states, xb)
+        preds, _ = _pool_model_preds(models, cfg, use_pallas, states, xb)
 
         def one(p, cap):
             agg, raq, weights = _apply_gate(cfg.strategy, cfg.beta, p, acc,
@@ -343,7 +352,8 @@ def _fused_observe_all(models: tuple[str, ...], cfg: SizeyConfig,
     """All-model fit (or incremental update) + in-sample refresh + decision
     cache, one dispatch. ``incremental=False`` is the paper's default
     full-retrain mode (incl. MLP HPO); ``incremental=True`` takes the
-    previous states and the newest buffer slot."""
+    previous states and the newest buffer slot. Also returns the in-sample
+    k-NN's fallback flag (``knn.insample_batch``)."""
 
     def observe_fn(states, xs, ys, runtimes, mask, new_idx, seed, log_agg,
                    log_actual, log_runtime, log_mask, log_model_preds):
@@ -361,13 +371,14 @@ def _fused_observe_all(models: tuple[str, ...], cfg: SizeyConfig,
                                **kw)
                     if incremental else mod.fit(xs, ys, mask, rng, cfg, **kw))
         new_states = tuple(new_states)
-        insample = _pool_model_preds(models, cfg, use_pallas, new_states, xs)
+        insample, fell_back = _pool_model_preds(
+            models, cfg, use_pallas, new_states, xs, insample=True)
         with jax.named_scope("combine"):
             cache = _decision_cache_core(
                 cfg.strategy, cfg.alpha, cfg.beta, ttf, cfg.adaptive_alpha,
                 insample, ys, runtimes, mask, log_agg, log_actual,
                 log_runtime, log_mask, log_model_preds)
-        return new_states, insample, cache
+        return new_states, insample, cache, fell_back
 
     return jax.jit(observe_fn)
 
@@ -380,18 +391,20 @@ def _fused_refresh_all(models: tuple[str, ...], cfg: SizeyConfig,
     full retrains of the ``refit_growth`` schedule. Newly appended history
     and prequential-log rows flow into the accuracy score and the offset
     selector immediately; only the model parameters stay at their last-fit
-    values. One dispatch, no training step."""
+    values. One dispatch, no training step. Also returns the in-sample
+    k-NN's fallback flag."""
 
     def refresh_fn(states, xs, ys, runtimes, mask, log_agg, log_actual,
                    log_runtime, log_mask, log_model_preds):
         TRACE_COUNTS["refresh"] += 1
-        insample = _pool_model_preds(models, cfg, use_pallas, states, xs)
+        insample, fell_back = _pool_model_preds(
+            models, cfg, use_pallas, states, xs, insample=True)
         with jax.named_scope("combine"):
             cache = _decision_cache_core(
                 cfg.strategy, cfg.alpha, cfg.beta, ttf, cfg.adaptive_alpha,
                 insample, ys, runtimes, mask, log_agg, log_actual,
                 log_runtime, log_mask, log_model_preds)
-        return insample, cache
+        return insample, cache, fell_back
 
     return jax.jit(refresh_fn)
 
@@ -709,13 +722,13 @@ class SizeyPredictor:
                 self._refit_fused(key, pool, seed, mask=jnp.asarray(trunc))
                 fn = _fused_refresh_all(self.models, self.cfg, self.ttf,
                                         self.use_pallas)
-                self._count_dispatch("refresh_pool", pool)
-                insample, cache = fn(
+                insample, cache, fell_back = fn(
                     self.states[key], pool.xs, pool.ys, pool.runtimes,
                     pool.mask, pool.log_agg, pool.log_actual,
                     pool.log_runtime, pool.log_mask, pool.log_model_preds)
                 self._cache[key] = cache
                 pool.insample_preds = insample
+                self._count_dispatch("refresh_pool", pool, fell_back)
             else:
                 self._refit_fused(key, pool, seed)
             self._fit_serial[key] = m
@@ -745,24 +758,30 @@ class SizeyPredictor:
             return
         fn = _fused_refresh_all(self.models, self.cfg, self.ttf,
                                 self.use_pallas)
-        self._count_dispatch("refresh_pool", pool)
         with _span("refresh", pool=f"{key[0]}@{key[1]}", n=pool.count,
                    cap=pool.cap):
-            insample, cache = fn(self.states[key], pool.xs, pool.ys,
-                                 pool.runtimes, pool.mask, pool.log_agg,
-                                 pool.log_actual, pool.log_runtime,
-                                 pool.log_mask, pool.log_model_preds)
+            insample, cache, fell_back = fn(
+                self.states[key], pool.xs, pool.ys, pool.runtimes,
+                pool.mask, pool.log_agg, pool.log_actual, pool.log_runtime,
+                pool.log_mask, pool.log_model_preds)
             self._cache[key] = cache
             pool.insample_preds = insample
             jax.block_until_ready(insample)
+        self._count_dispatch("refresh_pool", pool, fell_back)
 
-    def _count_dispatch(self, kind: str, pool) -> None:
-        """Count one observe or refresh launch, and the distances its
-        in-sample k-NN evaluates: the pool's buffer against itself."""
+    def _count_dispatch(self, kind: str, pool, fell_back) -> None:
+        """Count one finished observe or refresh launch, and the distances
+        its in-sample k-NN evaluated (``knn.insample_pairs``). Where the
+        k-NN took its sorted path the launch's fallback flag is read:
+        ``knn_fallback`` counts the launches that took the brute force."""
         DISPATCH_COUNTS[kind] += 1
         if "knn" in self.models:
-            DISPATCH_COUNTS["knn_pairs"] += MODEL_MODULES["knn"].pairs(
-                pool.cap, pool.cap)
+            knn = MODEL_MODULES["knn"]
+            d = pool.xs.shape[1]
+            fell = knn.sorted_insample(pool.cap, d) and bool(fell_back)
+            DISPATCH_COUNTS["knn_fallback"] += int(fell)
+            DISPATCH_COUNTS["knn_pairs"] += knn.insample_pairs(
+                pool.cap, d, self.cfg.knn_k, fell)
 
     def _note_fit(self, key, pool) -> None:
         self._fit_cap[key] = pool.cap
@@ -797,10 +816,9 @@ class SizeyPredictor:
         incremental = key in self.states and self.cfg.incremental
         fn = _fused_observe_all(self.models, self.cfg, self.ttf,
                                 self.use_pallas, incremental)
-        self._count_dispatch("observe_pool", pool)
         with _span("observe", pool=f"{key[0]}@{key[1]}", n=pool.count,
                    cap=pool.cap):
-            states, insample, cache = fn(
+            states, insample, cache, fell_back = fn(
                 self.states[key] if incremental else None, pool.xs, pool.ys,
                 pool.runtimes, pool.mask if mask is None else mask,
                 pool.count - 1, seed,
@@ -814,6 +832,7 @@ class SizeyPredictor:
                 for m, s in zip(self.models, states))
             pool.insample_preds = insample
             jax.block_until_ready(insample)
+        self._count_dispatch("observe_pool", pool, fell_back)
 
     def _observe_loop(self, key, pool, seed: int) -> None:
         """Pre-fusion reference: per-model fit/update dispatches plus an

@@ -93,6 +93,18 @@ def test_fused_observe_full_fit_compiles(one_chip, cap):
     # the in-sample k-NN never holds its CAP x CAP distances (1 GiB alone
     # at CAP 16384): the compiler fuses them into the top-k
     assert compiled.memory_analysis().temp_size_in_bytes < 2**30
+    # with one feature and CAP >= 2048 the k-NN selects from sorted
+    # windows; its brute-force top-k for every one of the CAP rows runs
+    # only in the conditional's fallback, lax.cond's branch 0 (its false
+    # branch)
+    lines = compiled.as_text().splitlines()
+    sorted_path = any(" conditional(" in l and "/knn/cond\"" in l
+                      for l in lines)
+    assert sorted_path == MODEL_MODULES["knn"].sorted_insample(cap, 1)
+    topk = [l for l in lines if 'custom_call_target="TopK"' in l
+            and f"f32[{cap}," in l.split("custom-call(")[0]]
+    assert topk and all(("/knn/cond/branch_0_fun/" in l) == sorted_path
+                        for l in topk)
 
 
 @pytest.mark.parametrize("cap,d", [(4096, 1), (2048, 2)])
@@ -111,7 +123,8 @@ def test_forest_fit_has_no_scatter(one_chip, cap, d):
 def test_fused_predict_compiles(one_chip):
     cap, k = 1024, 64
     observe = _fused_observe_all(MODELS, CFG, 1.0, True, False)
-    states, _, cache = jax.eval_shape(observe, *_observe_args(one_chip, cap))
+    states, _, cache, _ = jax.eval_shape(observe,
+                                         *_observe_args(one_chip, cap))
     # the predictor dispatches its predict view of the states: the fields
     # predict never reads are dropped (SizeyPredictor._pview)
     pview = tuple(

@@ -99,6 +99,128 @@ def test_knn_pairs_is_what_the_top_k_reads(queries, cap):
     assert math.prod(shape) == knn.pairs(queries, cap)
 
 
+@pytest.fixture
+def sorted_from(monkeypatch):
+    """Sets, for one test, the smallest buffer that takes the k-NN's sorted
+    in-sample path; the fused programs retrace on both sides of it."""
+    from repro.core.predictor import _fused_observe_all, _fused_refresh_all
+
+    def clear():
+        _fused_observe_all.cache_clear()
+        _fused_refresh_all.cache_clear()
+
+    def set_to(n_rows):
+        monkeypatch.setattr(knn, "_SORTED_MIN_CAP", n_rows)
+        clear()
+    yield set_to
+    clear()
+
+
+def _insample_pool(case, cap, seed=0):
+    """One-feature buffers for the in-sample exactness cases, masked rows
+    scattered through the buffer as in ``_knn_pool``. They hold zeros, as
+    provenance pads do, except in ``junk_pads``: more distinct values
+    than the sorted path answers by brute force."""
+    k = CFG.knn_k
+    rng = np.random.default_rng(seed)
+    xs = rng.uniform(0.1, 8.0, cap).astype(np.float32)
+    ys = rng.uniform(1.0, 64.0, cap).astype(np.float32)
+    n = {"valid_0": 0, "valid_1": 1, "valid_k-1": k - 1,
+         "boundary": 3 * k + 1}.get(case, 2 * cap // 3)
+    valid = rng.permutation(cap)[:n]
+    if case in ("lognormal_runs", "refresh"):
+        # the traffic's input sizes: runs of identical rows at the clip
+        # edges, far longer than k
+        xs[valid] = np.clip(rng.lognormal(np.log(1.525), 0.8, n), 0.1, 6.0)
+        assert np.sum(xs[valid] == 6.0) > 4 * k
+    elif case == "all_equal":
+        xs[:] = 2.5
+    elif case == "grid":
+        xs[valid] = np.arange(n) % 9 * 0.25    # symmetric ties
+    elif case == "boundary":
+        # a run of k at -1 and two more a few ulp beyond it: the row at 0
+        # selects from the first run while the next one, just outside its
+        # window, is within the check's margin of the k-th distance
+        steps = np.nextafter(np.float32(-1.0), np.float32(-2.0))
+        outer = np.nextafter(steps, np.float32(-2.0))
+        xs[valid] = np.repeat(np.float32([0.0, -1.0, steps, outer]),
+                              [1, k, k, k])
+    mask = np.zeros(cap, np.float32)
+    mask[valid] = 1.0
+    ys[mask == 0] = 1e9
+    if case != "junk_pads":
+        xs[mask == 0] = 0.0
+    queries = xs.copy()
+    if case == "refresh":
+        # a refresh queries the current buffer against the last fit's
+        # states: rows appended since then are pads there, and masked
+        queries[rng.permutation(np.flatnonzero(mask == 0))[:k]] = \
+            rng.uniform(0.1, 6.0, k)
+    return (jnp.asarray(xs[:, None]), jnp.asarray(ys), jnp.asarray(mask),
+            jnp.asarray(queries[:, None]))
+
+
+@pytest.mark.parametrize("case,cap", [
+    ("random_mask", 16), ("random_mask", 512), ("random_mask", 4096),
+    ("lognormal_runs", 4096), ("all_equal", 128), ("grid", 256),
+    ("valid_0", 16), ("valid_1", 64), ("valid_k-1", 64), ("refresh", 1024),
+    ("boundary", 64), ("junk_pads", 512)])
+def test_knn_insample_is_the_brute_force_bit_for_bit(case, cap,
+                                                    sorted_from):
+    """The sorted in-sample path gives today's brute force and the plain
+    reference bit for bit at every row, masked rows included; it falls
+    back only where its boundary check fails or queries are left
+    unanswered."""
+    from chipbench.harness import reference
+    sorted_from(0)            # the sorted path at every buffer size
+    xs, ys, mask, queries = _insample_pool(case, cap)
+    st = knn.fit(xs, ys, mask, KEY, CFG)
+    got, fell_back = jax.jit(
+        lambda s, q: knn.insample_batch(s, q, k=CFG.knn_k))(st, queries)
+    brute = jax.jit(
+        lambda s, q: knn.predict_batch(s, q, k=CFG.knn_k))(st, queries)
+    ref = reference.knn_predict(xs, ys, mask, queries, CFG.knn_k)
+    assert np.array_equal(np.asarray(got), np.asarray(brute))
+    assert np.array_equal(np.asarray(got), np.asarray(ref))
+    assert bool(fell_back) == (case in ("boundary", "junk_pads"))
+
+
+@pytest.mark.parametrize("d,case", [(1, "sorted"), (2, "brute"),
+                                    (1, "fallback"), (1, "small")])
+def test_knn_pairs_and_fallback_per_launch(d, case, sorted_from):
+    """Each observe adds the distances its in-sample k-NN evaluated: on the
+    sorted path a window of 5k per sorted slot and 8 brute-force values
+    against every row; CAP² for d = 2, below the sorted path's smallest
+    buffer, and where the launch fell back. ``knn_fallback`` counts only
+    the fallbacks."""
+    from repro import obs
+    from repro.core.predictor import DISPATCH_COUNTS, SizeyPredictor
+    k = CFG.knn_k
+    if case != "small":       # "small" keeps 128 rows below the real gate
+        sorted_from(0)
+    cfg = dataclasses.replace(CFG, mlp_train_steps=8, hpo=False)
+    pred = SizeyPredictor(cfg, n_features=d, default_machine_cap_gb=64.0)
+    if case == "fallback":     # _insample_pool's "boundary" rows
+        steps = np.nextafter(np.float32(-1.0), np.float32(-2.0))
+        outer = np.nextafter(steps, np.float32(-2.0))
+        xs = np.repeat(np.float32([0.0, -1.0, steps, outer]), [1, k, k, k])
+    else:
+        xs = 1.0 + np.arange(12, dtype=np.float32)
+
+    def complete(x):
+        dec = pred.predict("t", "m", [float(x)] * d, 8.0)
+        pred.observe(dec, 1.0 + 0.1 * abs(float(x)), 0.1)
+    for x in xs:
+        complete(x)
+    cap = pred.db.pool("t", "m").cap
+    with obs.scoped_counters(DISPATCH_COUNTS) as dc:
+        complete(0.0)
+        assert dc["observe_pool"] == 1
+        assert dc["knn_pairs"] == (cap * (5 * k + 8) if case == "sorted"
+                                   else cap * cap)
+        assert dc["knn_fallback"] == (case == "fallback")
+
+
 @pytest.mark.parametrize("refit_growth,span", [(0.0, "observe"),
                                                (0.5, "refresh")])
 def test_knn_pairs_count_and_span_cap(refit_growth, span):
