@@ -3,12 +3,12 @@ from __future__ import annotations
 
 import numpy as np
 
-from repro.workflow.accounting import (DEFAULT_CHECKPOINT_FRAC,
-                                       FAILURE_STRATEGIES, doubling_retry)
+from repro.workflow.accounting import FAILURE_STRATEGIES, doubling_retry
+from repro.workflow.simulator import SizingMethod
 from repro.workflow.trace import TaskInstance
 
 
-class HistoryMethod:
+class HistoryMethod(SizingMethod):
     """Per-(task_type, machine) observation history + doubling retry.
 
     ``failure_strategy`` is the Ponder-style crash handling the cluster
@@ -22,8 +22,6 @@ class HistoryMethod:
 
     name = "history"
     min_history = 3
-    failure_strategy = "retry_same"
-    checkpoint_frac = DEFAULT_CHECKPOINT_FRAC
 
     def __init__(self, machine_cap_gb: float = 128.0, *,
                  failure_strategy: str | None = None):
@@ -59,10 +57,7 @@ class HistoryMethod:
                 np.asarray(self._ys.get(k, [])),
                 np.asarray(self._rts.get(k, [])))
 
-    # SizingMethod protocol -------------------------------------------------
-    def allocate(self, task: TaskInstance) -> float:
-        raise NotImplementedError
-
+    # SizingMethod ----------------------------------------------------------
     def retry(self, task: TaskInstance, attempt: int,
               last_alloc_gb: float) -> float:
         return doubling_retry(last_alloc_gb, self.cap_for(task))

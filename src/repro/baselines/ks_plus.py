@@ -20,9 +20,9 @@ the same adaptations the other baselines get:
   * failure handling is the common doubling retry ladder (flat retries —
     a plan that OOMed is not re-trusted), clamped to the machine/node cap.
 
-The method exposes ``plan_for`` so both engines execute the k-segment
-reservation with RESIZE events; engines without plan support still get a
-safe peak-level allocation (``allocate`` returns the plan max).
+The method overrides ``plan_for`` so both engines execute the k-segment
+reservation with RESIZE events; ``allocate`` returns the plan max, the
+peak-level allocation a flat retry starts from.
 """
 from __future__ import annotations
 
@@ -87,7 +87,7 @@ class KSPlusMethod(HistoryMethod):
         self._seg_cache[key] = (bounds, models)
         return bounds, models
 
-    # SizingMethod protocol -------------------------------------------------
+    # SizingMethod ----------------------------------------------------------
     def allocate(self, task: TaskInstance) -> float:
         cap = self.cap_for(task)
         key = self._key(task)

@@ -1,4 +1,4 @@
-"""Adapter exposing SizeyPredictor through the SizingMethod protocol.
+"""Adapter exposing SizeyPredictor through the SizingMethod seam.
 
 ``temporal_k`` switches the method onto the temporal subsystem: the
 :class:`~repro.core.temporal.predictor.TemporalSizeyPredictor` predicts a
@@ -51,11 +51,12 @@ from repro.obs.quality import QUALITY_KIND
 from repro.obs.risk import RISK_KIND
 from repro.workflow.accounting import (DEFAULT_CHECKPOINT_FRAC,
                                        FAILURE_STRATEGIES)
+from repro.workflow.simulator import SizingMethod
 from repro.workflow.trace import TaskInstance
 
 
-class SizeyMethod:
-    """The Sizey predictor behind the ``SizingMethod`` protocol.
+class SizeyMethod(SizingMethod):
+    """The Sizey predictor behind the :class:`SizingMethod` seam.
 
     One adapter composes every subsystem: ``temporal_k=K`` switches onto
     time-segmented reservation plans, ``risk=...`` onto priced
@@ -68,6 +69,8 @@ class SizeyMethod:
     crash counters) — no rng, no wall clock — so serial runs, cluster
     runs and journal-replayed resumes reproduce decisions bitwise.
     """
+
+    batched_sizing = True
 
     def __init__(self, cfg: SizeyConfig | None = None, *, ttf: float = 1.0,
                  machine_cap_gb: float = 128.0, name: str | None = None,
@@ -135,6 +138,11 @@ class SizeyMethod:
         # re-executed sizing wave continues the stream bitwise
         self._pressure = 0.0
         self._risk_seq = len(self.predictor.db.aux.get(RISK_KIND, ()))
+
+    @property
+    def db(self) -> ProvenanceDB:
+        """The predictor's provenance db (what a journal attaches to)."""
+        return self.predictor.db
 
     def _crash_aware_alloc(self, decision) -> float:
         """Fold the observed crash rate into the offset choice (the
@@ -271,27 +279,16 @@ class SizeyMethod:
         return _auto_checkpoint_frac(self.risk.cfg, self._crash_p())
 
     def allocate(self, task: TaskInstance) -> float:
-        """Size one task's first attempt: predict -> (crash-aware
-        offset) -> (risk band reprice) -> clamp. The decision stays
-        pending until :meth:`complete`/:meth:`abandon`; replayed waves
-        never re-enter here — journaled allocations apply verbatim."""
-        if self.temporal:
-            return self.allocate_batch([task])[0]
-        # heterogeneous traces carry per-instance machine caps; route them
-        # into the pool so clamping follows the task's machine class
-        decision = self.predictor.predict(
-            task.task_type, task.machine, task.features, task.user_preset_gb,
-            machine_cap_gb=task.machine_cap_gb)
-        self._pending[id(task)] = decision
-        alloc = self._crash_aware_alloc(decision)
-        if self.risk is not None:
-            alloc = self._risk_alloc(decision, alloc)
-        return alloc
+        """Size one task's first attempt: a wave of one."""
+        return self.allocate_batch([task])[0]
 
     def allocate_batch(self, tasks: list[TaskInstance]) -> list[float]:
         """Decide a burst of submissions with one fused dispatch per pool
         (temporal mode stacks every task's k segments into that same
-        dispatch)."""
+        dispatch): predict -> (crash-aware offset) -> (risk band reprice)
+        -> clamp. Each decision stays pending until
+        :meth:`complete_batch`/:meth:`abandon`; replayed waves never
+        re-enter here — journaled allocations apply verbatim."""
         decisions = self.predictor.predict_batch(tasks)
         for task, decision in zip(tasks, decisions):
             self._pending[id(task)] = decision
@@ -337,24 +334,16 @@ class SizeyMethod:
 
     def complete(self, task: TaskInstance, first_alloc_gb: float,
                  attempts: int) -> None:
-        """Observe a completion: fold the measured peak/runtime into the
-        pool (amortized refit), the prequential residual log, and the
-        telemetry streams. Called once per task, live only — replayed
-        completions were observed before the crash and are skipped."""
-        decision = self._pending.pop(id(task))
-        self._note_completion(task)
-        if self.temporal:
-            self.predictor.observe(decision, task, attempts)
-        else:
-            self.predictor.observe(decision, task.actual_peak_gb,
-                                   task.runtime_h, attempts, task.workflow)
-        if self.quality:
-            self._record_quality([(decision, task, first_alloc_gb)])
+        """Observe one completion: a wave of one."""
+        self.complete_batch([(task, first_alloc_gb, attempts)])
 
     def complete_batch(self, items) -> None:
         """Observe a wave of simultaneous completions with one fused
         observe dispatch per pool (``items``: (task, first_alloc_gb,
-        attempts) tuples — the cluster engine's completion-wave API)."""
+        attempts) tuples): fold the measured peaks/runtimes into the pools
+        (amortized refit), the prequential residual log, and the telemetry
+        streams. Live only — replayed completions were observed before the
+        crash and are skipped."""
         for task, _first, _attempts in items:
             self._note_completion(task)
         completions = [(self._pending.pop(id(task)), task, first, attempts)

@@ -1,4 +1,3 @@
-from repro.serving.engine import ServeEngine, Request
 from repro.serving.scheduler_service import (AdmissionError,
                                              SchedulerService,
                                              TransientRejection,

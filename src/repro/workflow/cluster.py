@@ -50,7 +50,7 @@ possibly different) memory capacity:
     methods produce via capacity clamping) always places on an idle node.
     Resizes mutate the per-token held amount, so the invariant survives
     any shrink/grow sequence;
-  * *temporal* methods (exposing ``plan_for``) attach a multi-segment
+  * *temporal* methods (overriding ``plan_for``) attach a multi-segment
     :class:`~repro.core.temporal.segments.ReservationPlan` to an attempt:
     dispatch reserves the FIRST segment only, and a ``RESIZE`` event at
     each predicted segment boundary shrinks or grows the reservation in
@@ -66,8 +66,8 @@ possibly different) memory capacity:
     machinery is provably inert at k=1 (asserted in
     ``tests/test_temporal.py``);
   * simultaneous completions (finish events draining at one clock value)
-    are observed as ONE batch: methods exposing ``complete_batch`` get the
-    whole wave and fuse the model updates into one observe dispatch per
+    are observed as ONE batch: ``complete_batch`` gets the whole wave, and
+    batched methods fuse the model updates into one observe dispatch per
     pool (``DISPATCH_COUNTS['observe_pool']`` asserts the bound);
     same-clock ``RESIZE`` runs drain the same way — one wave applied in
     pop order (``n_resize_waves`` counts them), with the node's zero-dt
@@ -107,8 +107,7 @@ import numpy as np
 
 from repro.obs.trace import span as _span
 from repro.utils.misc import stable_hash
-from repro.workflow.accounting import (DEFAULT_CHECKPOINT_FRAC,
-                                       FAILURE_STRATEGIES, AttemptLedger,
+from repro.workflow.accounting import (FAILURE_STRATEGIES, AttemptLedger,
                                        TaskOutcome)
 from repro.workflow.simulator import ClusterMetrics, SimResult, SizingMethod
 from repro.workflow.trace import TaskInstance, WorkflowTrace
@@ -827,25 +826,18 @@ class ClusterEngine:
         self.place = PLACEMENT_POLICIES[policy]
         self.policy = policy
         self.backfill_depth = backfill_depth
-        self.failure_strategy = getattr(method, "failure_strategy",
-                                        "retry_same")
+        self.failure_strategy = method.failure_strategy
         # "auto": the method picks each task's strategy + checkpoint
         # cadence per pool at sizing time (risk-priced methods); choices
         # are journaled per sized task so replay never re-asks the method
         # (its counters sit at kill-time values during replay)
         self.strategy_auto = self.failure_strategy == "auto"
-        if self.strategy_auto:
-            if not (hasattr(method, "strategy_for")
-                    and hasattr(method, "checkpoint_frac_for")):
-                raise ValueError(
-                    "failure_strategy='auto' needs a method exposing "
-                    "strategy_for and checkpoint_frac_for")
-        elif self.failure_strategy not in FAILURE_STRATEGIES:
+        if (not self.strategy_auto
+                and self.failure_strategy not in FAILURE_STRATEGIES):
             raise ValueError(f"unknown failure strategy "
                              f"{self.failure_strategy!r} "
                              f"(have {FAILURE_STRATEGIES} + 'auto')")
-        self.checkpoint_frac = float(getattr(method, "checkpoint_frac",
-                                             DEFAULT_CHECKPOINT_FRAC))
+        self.checkpoint_frac = float(method.checkpoint_frac)
         if straggler_factor < 1.0:
             raise ValueError(f"straggler_factor must be >= 1, "
                              f"got {straggler_factor}")
@@ -895,30 +887,6 @@ class ClusterEngine:
         self._cats_cache: dict[str, tuple] = {}
         self._node_tokens: list[dict[int, None]] = \
             [{} for _ in self.nodes]
-        self.has_batch = hasattr(method, "allocate_batch")
-        self.has_plan = hasattr(method, "plan_for")
-        self.has_complete_batch = hasattr(method, "complete_batch")
-        self.has_note = hasattr(method, "note_interruption")
-        self.has_abandon = hasattr(method, "abandon")
-        # quality telemetry (repro.obs.quality): stamp the method with the
-        # virtual clock before each live completion wave so its quality
-        # rows carry engine time. Replay never calls it — replayed
-        # completions were observed before the crash and their rows sit in
-        # the warm-start prefix.
-        self.has_note_clock = hasattr(method, "note_clock")
-        # risk pricing (repro.core.risk): feed the method the live sizing
-        # pressure at each scheduling round. Pressure is a pure function
-        # of engine state, so a repair-re-executed round samples the
-        # identical value; replay skips the call (journaled allocations
-        # are applied verbatim).
-        self.has_note_pressure = hasattr(method, "note_pressure")
-        # durability protocol (optional; see SizeyMethod): without the
-        # hooks, journal replay still re-applies the recorded allocations
-        # but cannot restore in-flight decision state — best-effort only
-        self.has_export_state = hasattr(method, "export_state")
-        self.has_restore_state = hasattr(method, "restore_state")
-        self.has_export_pending = hasattr(method, "export_pending")
-        self.has_restore_pending = hasattr(method, "restore_pending")
         self.rack_names = sorted({s.rack for s in specs
                                   if s.rack is not None})
         self.rack_members = {r: [i for i, s in enumerate(specs)
@@ -1124,8 +1092,7 @@ class ClusterEngine:
                 self.pending_arrivals += 1
 
     def _finish_aborted(self, entry: _Queued, t: float) -> None:
-        if self.has_abandon:
-            self.method.abandon(entry.task)
+        self.method.abandon(entry.task)
         self.outcomes.append(entry.ledger.outcome(
             submit_h=entry.ready_h,
             start_h=entry.start_h if entry.start_h is not None else t,
@@ -1178,7 +1145,7 @@ class ClusterEngine:
         if entry.ledger.failure_strategy == "retry_scaled":
             entry.ledger.refresh_pending = True
             self._refresh_dirty = True
-        if self.has_note and self._replay is None:
+        if self._replay is None:
             self.method.note_interruption(entry.task, t - started)
         self._jev("interrupt", list(entry.task.key))
         self.queue.requeue(entry)   # keeps its original FIFO seq
@@ -1461,20 +1428,14 @@ class ClusterEngine:
                     # replayed completions were observed before the crash
                     # (their task/log/curve rows are in the warm-start
                     # prefix): just drop the restored in-flight decisions
-                    if self.has_abandon:
-                        for e, _ in completed:
-                            method.abandon(e.task)
-                elif self.has_complete_batch:
-                    if self.has_note_clock:
-                        method.note_clock(clock)
+                    for e, _ in completed:
+                        method.abandon(e.task)
+                else:
+                    # quality telemetry (repro.obs.quality): the virtual
+                    # clock stamps the wave's quality rows with engine time
+                    method.note_clock(clock)
                     with _span("engine/complete_wave", n=len(items)):
                         method.complete_batch(items)
-                else:
-                    if self.has_note_clock:
-                        method.note_clock(clock)
-                    with _span("engine/complete_wave", n=len(items)):
-                        for task, first_alloc, attempts in items:
-                            method.complete(task, first_alloc, attempts)
         elif self.queue:
             # every queued task is sized, admitted (alloc <= its cap), all
             # nodes are up (no recover event pending) and idle — the
@@ -1485,10 +1446,11 @@ class ClusterEngine:
 
         # ----------------------------------------------- scheduling round
         clock = self.clock
-        if rec is None and self.has_note_pressure:
-            # live steps only: replayed waves re-apply journaled
-            # allocations, and a repair-re-executed round recomputes the
-            # identical sample from the restored engine state
+        if rec is None:
+            # risk pricing (repro.core.risk), live steps only: replayed
+            # waves re-apply journaled allocations, and a repair-re-executed
+            # round recomputes the identical sample from the restored
+            # engine state
             method.note_pressure(self.pressure())
         # the queue is permanently seq-sorted (_SeqQueue), so the unsized
         # wave is exactly this drain's arrivals (plus, defensively, any
@@ -1516,13 +1478,11 @@ class ClusterEngine:
                     entry.task, float(alloc), self._cap_for(entry.task),
                     self.ttf, failure_strategy=strat,
                     checkpoint_frac=cfrac)
-                if self.has_plan:
-                    # temporal reservation schedule for the first attempt
-                    # (set_plan drops 1-segment plans onto the flat path)
-                    plan = method.plan_for(entry.task)
-                    if plan is not None:
-                        entry.ledger.set_plan(
-                            plan.clamped(entry.ledger.cap_gb))
+                # temporal reservation schedule for the first attempt
+                # (set_plan drops 1-segment plans onto the flat path)
+                plan = method.plan_for(entry.task)
+                if plan is not None:
+                    entry.ledger.set_plan(plan.clamped(entry.ledger.cap_gb))
                 if entry.ledger.alloc_gb > entry.ledger.cap_gb:
                     # no node can ever satisfy the request: reject at
                     # admission (it would otherwise head-of-line block)
@@ -1637,8 +1597,9 @@ class ClusterEngine:
         self._jrec = None
         if jrec is not None:
             jrec["clock"] = self.clock
-            if self.has_export_state:
-                jrec["mstate"] = method.export_state()
+            mstate = method.export_state()
+            if mstate is not None:
+                jrec["mstate"] = mstate
             self._journal.append_step(jrec)
             self._journal.maybe_snapshot(self._step_idx, self.export_state)
         if rec is not None:
@@ -1743,16 +1704,15 @@ class ClusterEngine:
         method = self.method
         auto = self.strategy_auto and field == "sized"
         self._wave_strategies = None
+        self.n_size_calls += 1 if method.batched_sizing else len(wave)
         if rec is not None:
             js = rec[field]
             if [list(e.task.key) for e in wave] != [s[0] for s in js]:
                 raise RuntimeError(f"journal divergence: {field} wave "
                                    f"keys do not match the journal")
-            self.n_size_calls += 1 if self.has_batch else len(wave)
-            if self.has_restore_pending:
-                for e, s in zip(wave, js):
-                    if s[2] is not None:
-                        method.restore_pending(e.task, s[2])
+            for e, s in zip(wave, js):
+                if s[2] is not None:
+                    method.restore_pending(e.task, s[2])
             if auto:
                 if any(len(s) < 5 for s in js):
                     raise RuntimeError(
@@ -1762,12 +1722,7 @@ class ClusterEngine:
                 self._wave_strategies = [(s[3], float(s[4])) for s in js]
             return [s[1] for s in js]
         with _span("engine/sizing_wave", kind=field, n=len(wave)):
-            if self.has_batch:
-                self.n_size_calls += 1
-                allocs = method.allocate_batch([e.task for e in wave])
-            else:
-                self.n_size_calls += len(wave)
-                allocs = [method.allocate(e.task) for e in wave]
+            allocs = method.allocate_batch([e.task for e in wave])
         if auto:
             # asked AFTER sizing so the method can read each task's
             # fresh in-flight decision (per-pool RAQ trust)
@@ -1777,9 +1732,7 @@ class ClusterEngine:
                 for e in wave]
         if jrec is not None:
             jrec[field] = [
-                [list(e.task.key), float(a),
-                 (method.export_pending(e.task)
-                  if self.has_export_pending else None)]
+                [list(e.task.key), float(a), method.export_pending(e.task)]
                 for e, a in zip(wave, allocs)]
             if auto:
                 for s, (strat, cfrac) in zip(jrec[field],
@@ -1857,7 +1810,7 @@ class ClusterEngine:
     def _attach_journal(self, journal, *, resumed_from=None) -> None:
         self._journal = journal
         journal.begin(config=self._config, trace_fp=self._trace_fp(),
-                      method_name=getattr(self.method, "name", "?"),
+                      method_name=self.method.name,
                       resumed_from=resumed_from)
 
     def _trace_fp(self) -> int:
@@ -1977,17 +1930,20 @@ class ClusterEngine:
             "n_recoveries": self.n_recoveries,
             "n_replayed_steps": self.n_replayed_steps,
         }
-        if self.has_export_state:
-            state["mstate"] = self.method.export_state()
-        if self.has_export_pending:
-            pend = []
-            for e in self.queue:
-                if e.ledger is not None and not e.ledger.aborted:
-                    pend.append([list(e.task.key),
-                                 self.method.export_pending(e.task)])
-            for e, _n, _s in self.running.values():
+        mstate = self.method.export_state()
+        pend = []
+        for e in self.queue:
+            if e.ledger is not None and not e.ledger.aborted:
                 pend.append([list(e.task.key),
                              self.method.export_pending(e.task)])
+        for e, _n, _s in self.running.values():
+            pend.append([list(e.task.key),
+                         self.method.export_pending(e.task)])
+        # a method with no journaled state and no in-flight decisions
+        # adds no method section to the snapshot
+        if mstate is not None:
+            state["mstate"] = mstate
+        if mstate is not None or any(b is not None for _k, b in pend):
             state["pending"] = pend
         return state
 
@@ -2050,13 +2006,11 @@ class ClusterEngine:
             # snapshots never serialize the free-capacity index: it is a
             # pure function of the node states restored above
             self._findex.rebuild()
-        if state.get("mstate") is not None and self.has_restore_state:
+        if state.get("mstate") is not None:
             self.method.restore_state(state["mstate"])
-        if self.has_restore_pending:
-            for key, blob in state.get("pending", []):
-                if blob is not None:
-                    self.method.restore_pending(self.by_key[tuple(key)],
-                                                blob)
+        for key, blob in state.get("pending", []):
+            if blob is not None:
+                self.method.restore_pending(self.by_key[tuple(key)], blob)
 
     def _cold_restart(self) -> None:
         """The crash took the workers with the scheduler: interrupt every
@@ -2106,13 +2060,13 @@ class ClusterEngine:
                   straggler_seed=cfg["straggler_seed"])
         if run.trace_fp != eng._trace_fp():
             raise ValueError("journal was written for a different trace")
-        if run.method_name != getattr(method, "name", "?"):
+        if run.method_name != method.name:
             raise ValueError(
                 f"journal was written by method {run.method_name!r}, "
-                f"recovering with {getattr(method, 'name', '?')!r}")
+                f"recovering with {method.name!r}")
         if run.snapshot is not None:
             eng._restore_state(run.snapshot)
-        if run.mstate is not None and eng.has_restore_state:
+        if run.mstate is not None:
             # kill-time method counters: the tail's last journaled state
             # (replay skips note_interruption/complete, so counters do
             # not double-advance)
@@ -2179,12 +2133,13 @@ def simulate_cluster(trace: WorkflowTrace, method: SizingMethod,
     how the attempt re-runs — follows the method's ``failure_strategy``
     (``retry_same`` / ``retry_scaled`` / ``checkpoint``; see
     :mod:`repro.workflow.accounting`). ``retry_scaled`` re-sizes
-    interrupted tasks through the method before re-dispatch; methods
-    exposing ``note_interruption`` observe every crash (crash-aware
+    interrupted tasks through the method before re-dispatch; the
+    method's ``note_interruption`` observes every crash (crash-aware
     sizing feeds on this).
 
-    Any :class:`SizingMethod` runs unmodified; methods exposing
-    ``allocate_batch`` (Sizey) get each ready wave as one burst. Passing
+    Any :class:`SizingMethod` runs unmodified; every ready wave goes to
+    its ``allocate_batch`` as one burst (one fused dispatch per pool for
+    Sizey). Passing
     a :class:`~repro.workflow.journal.Journal` makes the run *durable*:
     every engine transition is WAL-logged and periodically snapshotted,
     and a killed run resumes mid-workflow via

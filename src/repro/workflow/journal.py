@@ -89,13 +89,12 @@ class Journal:
     @classmethod
     def attach(cls, method, *, snapshot_every: int = 64) -> "Journal":
         """Journal onto the provenance db ``method`` already persists to
-        (the usual construction: one file per durable run)."""
-        predictor = getattr(method, "predictor", None)
-        db = getattr(predictor, "db", None) or getattr(method, "db", None)
-        if db is None:
-            raise ValueError(f"method {getattr(method, 'name', method)!r} "
+        (its ``SizingMethod.db``; the usual construction: one file per
+        durable run)."""
+        if method.db is None:
+            raise ValueError(f"method {method.name!r} "
                              f"exposes no provenance db to journal onto")
-        return cls(db, snapshot_every=snapshot_every)
+        return cls(method.db, snapshot_every=snapshot_every)
 
     @property
     def path(self) -> str:

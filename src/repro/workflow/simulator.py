@@ -12,66 +12,130 @@ Replays a trace in submission order against a sizing method. Semantics:
     capacity is reached; if even the capacity cannot fit the task the task
     is aborted (never happens with the shipped generators).
 
-The method interface is minimal so Sizey, all baselines, and the LM-job
-sizer share it: allocate / retry / complete. The per-attempt arithmetic
-lives in :mod:`repro.workflow.accounting` and is shared with the
-event-driven multi-node engine (:mod:`repro.workflow.cluster`) — the
-serial replay here is the 1-node special case of the same state machine.
+Sizey and every baseline derive from the :class:`SizingMethod` base
+below: allocate / retry / complete, with a default for each other hook
+the engines call. The per-attempt arithmetic lives in
+:mod:`repro.workflow.accounting` and is shared with the event-driven
+multi-node engine (:mod:`repro.workflow.cluster`) — the serial replay
+here is the 1-node special case of the same state machine.
 """
 from __future__ import annotations
 
 import dataclasses
-from typing import Protocol
 
-from repro.workflow.accounting import MAX_ATTEMPTS, AttemptLedger, TaskOutcome
+from repro.core.temporal.segments import ReservationPlan
+from repro.workflow.accounting import (DEFAULT_CHECKPOINT_FRAC, MAX_ATTEMPTS,
+                                       AttemptLedger, TaskOutcome)
 from repro.workflow.trace import TaskInstance, WorkflowTrace
 
 __all__ = ["SizingMethod", "TaskOutcome", "ClusterMetrics", "SimResult",
            "MAX_ATTEMPTS", "simulate"]
 
 
-class SizingMethod(Protocol):
+class SizingMethod:
+    """The seam between the engines and a sizing method.
+
+    A method defines ``name``, :meth:`allocate`, :meth:`retry` and
+    :meth:`complete`; every other hook has a default here that the
+    engines (:func:`simulate`, :class:`~repro.workflow.cluster.ClusterEngine`)
+    call unconditionally, so a method overrides only what it uses.
+    """
+
     name: str
+    # crash handling the cluster engine applies to this method's attempts
+    # (see repro.workflow.accounting; "auto" asks strategy_for per task)
+    failure_strategy: str = "retry_same"
+    checkpoint_frac: float = DEFAULT_CHECKPOINT_FRAC
+    # True when allocate_batch sizes a whole wave in one call: the engine
+    # then counts one size call per wave (ClusterMetrics.n_size_calls) and
+    # simulate(batch_stages=True) hands it whole stages; otherwise the
+    # count is one per task and stages are sized task by task
+    batched_sizing: bool = False
+    # provenance db a Journal attaches to (Journal.attach); None: the
+    # method persists nothing and cannot be journaled that way
+    db = None
 
     def allocate(self, task: TaskInstance) -> float:
         """First-attempt allocation in GB."""
+        raise NotImplementedError
 
     def retry(self, task: TaskInstance, attempt: int,
               last_alloc_gb: float) -> float:
         """Allocation for retry ``attempt`` (1-based) after a failure."""
+        raise NotImplementedError
 
     def complete(self, task: TaskInstance, first_alloc_gb: float,
                  attempts: int) -> None:
         """Task finished successfully; actual peak may now be observed."""
+        raise NotImplementedError
 
-    # Optional protocol extensions, discovered via hasattr:
-    #   allocate_batch(tasks) -> list[float]
-    #       size a whole ready wave in one fused dispatch per pool;
-    #   plan_for(task) -> ReservationPlan | None
-    #       time-segmented reservation for the allocation just returned by
-    #       allocate/allocate_batch (temporal methods). A 1-segment plan
-    #       (or None) runs on the legacy constant-reservation path;
-    #   complete_batch(items: list[tuple[task, first_alloc, attempts]])
-    #       observe a wave of simultaneous completions in one fused
-    #       observe dispatch per pool;
-    #   abandon(task)
-    #       drop in-flight state for an aborted task;
-    #   note_clock(t_h) / note_interruption(task, elapsed_h)
-    #       engine telemetry hooks, live steps only (quality rows /
-    #       crash-aware sizing counters — see SizeyMethod);
-    #   note_pressure(p)
-    #       live sizing pressure sample (ClusterEngine.pressure()) fed
-    #       before each scheduling round; risk-priced methods consume it.
-    #       The serial simulate() below never calls it, so serial runs
-    #       price at pressure 0.0 (generous sizing) by construction;
-    #   strategy_for(task) -> str / checkpoint_frac_for(task) -> float
-    #       per-task failure-strategy auto-selection (engine-side
-    #       failure_strategy="auto"): asked once per live sized wave and
-    #       journaled, never re-asked at replay;
-    #   export_state() / restore_state(state) and
-    #   export_pending(task) / restore_pending(task, blob)
-    #       durability protocol: journal-ride the method state seeds
-    #       cannot re-derive (see repro.workflow.journal).
+    def allocate_batch(self, tasks: list[TaskInstance]) -> list[float]:
+        """Size a whole ready wave (one fused dispatch per pool for a
+        batched method); the default sizes task by task."""
+        return [self.allocate(t) for t in tasks]
+
+    def complete_batch(self, items) -> None:
+        """Observe a wave of simultaneous completions (``items``: (task,
+        first_alloc_gb, attempts) tuples); the default observes them one
+        by one, in order."""
+        for task, first_alloc_gb, attempts in items:
+            self.complete(task, first_alloc_gb, attempts)
+
+    def plan_for(self, task: TaskInstance) -> ReservationPlan | None:
+        """Time-segmented reservation for the allocation just returned
+        (temporal methods). A 1-segment plan (or None, the default) runs
+        on the constant-reservation path."""
+        return None
+
+    def abandon(self, task: TaskInstance) -> None:
+        """Drop in-flight state for an aborted task (or, at journal
+        replay, for a completion observed before the crash)."""
+
+    def note_clock(self, t_h: float) -> None:
+        """Virtual clock of the live completion wave about to be observed
+        (cluster engine only; replay never calls it)."""
+
+    def note_interruption(self, task: TaskInstance,
+                          elapsed_h: float) -> None:
+        """A crash/preemption killed one attempt ``elapsed_h`` into its
+        run (cluster engine, live steps only)."""
+
+    def note_pressure(self, pressure: float) -> None:
+        """Live sizing pressure sample (``ClusterEngine.pressure()``) fed
+        before each live scheduling round. The serial :func:`simulate`
+        never calls it, so serial runs price at pressure 0.0."""
+
+    def strategy_for(self, task: TaskInstance) -> str:
+        """Per-task failure strategy under ``failure_strategy="auto"``:
+        asked once per live sized wave and journaled, never re-asked at
+        replay. Only methods that accept "auto" override it."""
+        raise NotImplementedError(
+            f"failure_strategy='auto' needs a method that picks "
+            f"strategy_for each task ({self.name!r} does not)")
+
+    def checkpoint_frac_for(self, task: TaskInstance) -> float:
+        """Per-task checkpoint cadence under ``failure_strategy="auto"``
+        (journaled alongside :meth:`strategy_for`)."""
+        return self.checkpoint_frac
+
+    # durability protocol (repro.workflow.journal): the method state that
+    # seeds cannot re-derive rides the journal
+    def export_state(self) -> dict | None:
+        """JSON-safe method counters, journaled once per step and in every
+        snapshot. None (the default): the method keeps no such state, and
+        step rows and snapshots carry no ``mstate``."""
+        return None
+
+    def restore_state(self, state: dict) -> None:
+        """Inverse of :meth:`export_state` (recovery)."""
+
+    def export_pending(self, task: TaskInstance) -> dict | None:
+        """JSON-safe in-flight decision of a sized task, journaled with
+        its sizing wave and in snapshots (None: nothing to restore)."""
+        return None
+
+    def restore_pending(self, task: TaskInstance, blob: dict) -> None:
+        """Inverse of :meth:`export_pending` (recovery)."""
 
 
 @dataclasses.dataclass
@@ -253,7 +317,7 @@ def simulate(trace: WorkflowTrace, method: SizingMethod,
     """Replay ``trace`` against ``method`` on one implicit machine.
 
     ``batch_stages=True`` submits each DAG stage as one burst through the
-    method's ``allocate_batch`` (if it has one) — the realistic cluster
+    ``allocate_batch`` of a ``batched_sizing`` method — the realistic cluster
     scenario where a scheduler dispatches a whole ready stage at once and
     Sizey amortizes K decisions into one device launch. Completions (and
     thus model updates) still happen per task, after the burst is sized.
@@ -263,13 +327,10 @@ def simulate(trace: WorkflowTrace, method: SizingMethod,
     """
     outcomes: list[TaskOutcome] = []
     clock = 0.0
-    batched = batch_stages and hasattr(method, "allocate_batch")
-    bursts = _bursts(trace.tasks) if batched else ([t] for t in trace.tasks)
+    bursts = (_bursts(trace.tasks) if batch_stages and method.batched_sizing
+              else ([t] for t in trace.tasks))
     for burst in bursts:
-        if batched:
-            allocs = [float(a) for a in method.allocate_batch(burst)]
-        else:
-            allocs = [float(method.allocate(t)) for t in burst]
+        allocs = [float(a) for a in method.allocate_batch(burst)]
         for task, first_alloc in zip(burst, allocs):
             o = _run_one(trace, method, task, first_alloc, ttf, clock)
             clock = o.finish_h
@@ -284,21 +345,19 @@ def _run_one(trace: WorkflowTrace, method: SizingMethod, task: TaskInstance,
     cap = (trace.machine_cap_gb if task.machine_cap_gb is None
            else task.machine_cap_gb)
     led = AttemptLedger(task, first_alloc, cap, ttf)
-    if hasattr(method, "plan_for"):
-        # temporal methods attach a reservation plan to the first attempt;
-        # on the serial machine resizes always succeed (one task at a
-        # time), so the plan only changes the waste/failure arithmetic.
-        # Retries fall back to the flat ladder (apply_retry drops the plan).
-        plan = method.plan_for(task)
-        if plan is not None:
-            led.set_plan(plan.clamped(cap))
+    # temporal methods attach a reservation plan to the first attempt;
+    # on the serial machine resizes always succeed (one task at a time),
+    # so the plan only changes the waste/failure arithmetic. Retries fall
+    # back to the flat ladder (apply_retry drops the plan).
+    plan = method.plan_for(task)
+    if plan is not None:
+        led.set_plan(plan.clamped(cap))
     while not led.will_succeed:
         if led.record_failure():
             break
         led.apply_retry(method)
     if led.aborted:
-        if hasattr(method, "abandon"):
-            method.abandon(task)  # let the method drop in-flight state
+        method.abandon(task)  # let the method drop in-flight state
     else:
         led.record_success()
         method.complete(task, first_alloc, led.attempts)

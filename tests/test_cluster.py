@@ -12,10 +12,11 @@ from repro.core.predictor import DISPATCH_COUNTS
 from repro.workflow import generate_workflow, simulate, simulate_cluster
 from repro.workflow.accounting import MAX_ATTEMPTS
 from repro.workflow.cluster import PLACEMENT_POLICIES
+from repro.workflow.simulator import SizingMethod
 from repro.workflow.trace import TaskInstance, WorkflowTrace
 
 
-class FixedMethod:
+class FixedMethod(SizingMethod):
     """Always allocates a fixed amount; doubles on failure."""
     name = "fixed"
 

@@ -13,6 +13,7 @@ from repro.core import SizeyConfig
 from repro.workflow import generate_workflow, simulate, simulate_cluster
 from repro.workflow.accounting import MAX_ATTEMPTS, AttemptLedger
 from repro.workflow.cluster import Node, NodeSpec, node_specs_from_caps
+from repro.workflow.simulator import SizingMethod
 from repro.workflow.trace import TaskInstance, WorkflowTrace
 
 
@@ -23,7 +24,7 @@ def _task(tt="A", idx=0, actual=10.0, runtime=1.0, deps=(), arrival=0.0,
                         machine_cap_gb=machine_cap)
 
 
-class MapMethod:
+class MapMethod(SizingMethod):
     """Allocates a fixed amount per task type; doubles on failure."""
     name = "map"
 
@@ -360,7 +361,7 @@ def test_sizey_pools_clamp_to_their_machine_class():
 def test_serial_replay_respects_per_task_machine_cap():
     # retry ladder on a heterogeneous trace clamps at the task's own class
     # cap (16 GB), not the trace-wide 128 GB machine
-    class Fixed:
+    class Fixed(SizingMethod):
         name = "fixed"
 
         def allocate(self, task):

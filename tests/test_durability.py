@@ -22,6 +22,7 @@ from repro.workflow.accounting import AttemptLedger
 from repro.workflow.cluster import (_RESIZE, ClusterEngine,
                                     simulate_cluster)
 from repro.workflow.journal import Journal, recover_run
+from repro.workflow.simulator import SizingMethod
 from repro.workflow.trace import TaskInstance, WorkflowTrace
 
 CAP = 64.0
@@ -305,7 +306,7 @@ def test_resumed_plan_schedules_only_remaining_boundaries():
     curve = ((0.25, 2.0), (0.5, 4.0), (1.0, 6.0))
     task = _task(actual=6.0, runtime=1.0, curve=curve)
 
-    class PlanMethod:
+    class PlanMethod(SizingMethod):
         name = "plan"
         failure_strategy = "checkpoint"
         checkpoint_frac = 0.25
@@ -480,7 +481,7 @@ def test_service_oom_storm_cannot_starve_other_tenant():
     storm_trace = _small_trace(seed=7, scale=0.06)
     calm_trace = _small_trace(seed=2, scale=0.02)
 
-    class StormMethod:
+    class StormMethod(SizingMethod):
         name = "storm"
 
         def allocate(self, task):

@@ -14,6 +14,7 @@ from repro.workflow.accounting import (DEFAULT_CHECKPOINT_FRAC,
                                        FAILURE_STRATEGIES, AttemptLedger)
 from repro.workflow.cluster import (NodeSpec, node_specs_from_caps,
                                     node_specs_from_racks)
+from repro.workflow.simulator import SizingMethod
 from repro.workflow.trace import TaskInstance, WorkflowTrace
 
 
@@ -23,7 +24,7 @@ def _task(tt="A", idx=0, actual=10.0, runtime=1.0, deps=(), arrival=0.0,
                         idx, arrival_h=arrival, deps=deps)
 
 
-class MapMethod:
+class MapMethod(SizingMethod):
     """Allocates a fixed amount per task type; doubles on failure."""
     name = "map"
 
@@ -446,7 +447,7 @@ def test_checkpoint_beats_retry_same_on_interruption_waste():
 
 # ------------------------------------------------- strategy: retry_scaled
 def test_retry_scaled_resizes_through_method_after_crash():
-    class Shrinking:
+    class Shrinking(SizingMethod):
         """First sizing says 20 GB; every re-sizing tightens to 8 GB."""
         name = "shrinking"
         failure_strategy = "retry_scaled"

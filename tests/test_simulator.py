@@ -1,13 +1,17 @@
 """Simulator semantics: strict limits, ttf accounting, retry ladders."""
+import os
+
 import pytest
 
-from repro.baselines import make_method
-from repro.workflow import generate_workflow, simulate
-from repro.workflow.simulator import simulate as _sim
+from chaos import assert_results_equal, kill_and_resume, run_journaled
+from repro.baselines import ALL_BASELINES, make_method
+from repro.core.provenance import ProvenanceDB
+from repro.workflow import generate_workflow, simulate, simulate_cluster
+from repro.workflow.simulator import SizingMethod, simulate as _sim
 from repro.workflow.trace import TaskInstance, WorkflowTrace
 
 
-class FixedMethod:
+class FixedMethod(SizingMethod):
     """Always allocates a fixed amount; doubles on failure."""
     name = "fixed"
 
@@ -109,3 +113,77 @@ def test_summary_reports_float_load_and_machine_cap():
     assert s["avg_instances_per_type"] == pytest.approx(
         len(trace.tasks) / s["n_task_types"])
     assert s["machine_cap_gb"] == trace.machine_cap_gb
+
+
+# ------------------------------------------------------ the SizingMethod seam
+class MinimalMethod(SizingMethod):
+    """Defines only allocate/retry/complete: every other hook the engines
+    call is the base class's default. Sizes one task type in three under
+    its peak, so the retry ladder runs; keeps a provenance db only when
+    given a journal path."""
+    name = "minimal"
+
+    def __init__(self, persist_path=None):
+        if persist_path is not None:
+            self.db = ProvenanceDB(persist_path=persist_path)
+        self.n_allocated = 0
+        self.completed = []
+
+    def allocate(self, task):
+        self.n_allocated += 1
+        return task.actual_peak_gb * (0.75 if task.index % 3 == 0 else 1.25)
+
+    def retry(self, task, attempt, last):
+        return last * 2.0
+
+    def complete(self, task, first_alloc, attempts):
+        self.completed.append(task.key)
+
+
+_CRASHES = dict(n_nodes=4, fail_rate_per_node_h=0.2, straggler_rate=0.1)
+
+
+@pytest.mark.parametrize("run", ["simulate", "simulate_cluster_crashes",
+                                 "journaled_warm_resume"])
+def test_minimal_method_runs_on_the_base_defaults(run, tmp_path):
+    trace = generate_workflow("eager", seed=3, scale=0.04,
+                              machine_cap_gb=64.0)
+    if run == "simulate":
+        m = MinimalMethod()
+        r = simulate(trace, m)
+        assert m.n_allocated == len(trace.tasks)
+        # batch_stages changes nothing for a method that sizes per task
+        assert simulate(trace, MinimalMethod(),
+                        batch_stages=True).outcomes == r.outcomes
+    elif run == "simulate_cluster_crashes":
+        m = MinimalMethod()
+        r = simulate_cluster(trace, m, **_CRASHES)
+        assert r.cluster.n_node_failures > 0
+        assert sum(o.interruptions for o in r.outcomes) > 0
+        # retry_same never re-sizes: one size call per task
+        assert r.cluster.n_size_calls == m.n_allocated
+        assert r.cluster.failure_strategy == "retry_same"
+    else:
+        path = str(tmp_path / "run.jsonl")
+        r = run_journaled(trace, MinimalMethod, path, snapshot_every=8,
+                          **_CRASHES)
+        size = os.path.getsize(path)
+        resumed, _eng = kill_and_resume(path, size // 2, trace,
+                                        MinimalMethod,
+                                        scratch=str(tmp_path / "cut.jsonl"))
+        assert_results_equal(r, resumed)
+        assert resumed.cluster.n_recoveries == 1
+        m = MinimalMethod()
+        assert r.outcomes == simulate_cluster(trace, m, **_CRASHES).outcomes
+    assert r.n_failures > 0
+    done = [o.task.key for o in r.outcomes if not o.aborted]
+    assert sorted(m.completed) == sorted(done)
+
+
+METHOD_NAMES = ("sizey", "sizey_risk", "sizey_risk_temporal",
+                "sizey_argmax", "sizey_temporal") + ALL_BASELINES
+
+
+@pytest.mark.parametrize("name", METHOD_NAMES)
+def test_every_method_derives_from_the_base(name):
+    assert isinstance(make_method(name, machine_cap_gb=64.0), SizingMethod)

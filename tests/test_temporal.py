@@ -16,6 +16,7 @@ from repro.core.temporal.segments import (ReservationPlan, fit_boundaries,
                                           uniform_boundaries)
 from repro.workflow import generate_workflow, simulate, simulate_cluster
 from repro.workflow.accounting import (MAX_GROW_FAILURES, AttemptLedger)
+from repro.workflow.simulator import SizingMethod
 from repro.workflow.trace import TaskInstance, WorkflowTrace
 
 
@@ -30,7 +31,7 @@ def _task(tt="A", idx=0, actual=10.0, runtime=1.0, curve=(), preset=64.0,
                         idx, deps=deps, usage_curve=curve)
 
 
-class FixedPlanMethod:
+class FixedPlanMethod(SizingMethod):
     """Allocates a fixed reservation plan; doubles flat on failure."""
     name = "fixed_plan"
 
@@ -290,7 +291,7 @@ def test_resize_disabled_matches_legacy_engine_bitwise():
     flat = simulate_cluster(trace, FixedPlanMethod(((1.0, 8.0),)), ttf=0.5,
                             n_nodes=2)
 
-    class Legacy:
+    class Legacy(SizingMethod):
         name = "legacy"
 
         def allocate(self, task):
